@@ -47,11 +47,14 @@ def _train_step_compare(out: dict) -> None:
         # time ONLY the jitted step on a fixed batch/state — host-side batch
         # synthesis must not dilute the fused-vs-dense device delta
         step, batch = sess.step_fn, sess.batch_for(0)
-        state = (sess.params, sess.opt_state, sess.ef_state)
+        state = [sess.params, sess.opt_state, sess.ef_state]
         key = jax.random.PRNGKey(0)
 
         def one(t):
-            return step(*state, batch, jax.random.fold_in(key, t), t)[3]
+            # the step donates its state: each call takes the last one's
+            out = step(*state, batch, jax.random.fold_in(key, t), t)
+            state[:] = out[:3]
+            return out[3]
 
         out[f"train_step_{carrier}_us"] = _bench(
             one, 0, iters=3, key=f"train_step_{carrier}")

@@ -18,7 +18,10 @@ What is measured, per arrival rate:
   (``launch/replica_worker.py``) tailing the stream over the transport
   layer with continuous sync during decode — recorded under
   ``serving_multiproc`` so the process boundary's cost sits next to the
-  in-process numbers it must be compared against;
+  in-process numbers it must be compared against. Left out, and the
+  output says why, where the workers could not start
+  (``fleet.can_spawn_workers``): on an accelerator this process holds the
+  chip;
 * the SAME load with the fleet tailing a REMOTE stream over ``tcp://``
   (``launch/transport.py::TailServer`` RPC — the wire a cross-machine
   replica actually rides) — recorded under ``serving_remote`` so the
@@ -113,35 +116,41 @@ def run(tiny: bool = False) -> dict:
         # the multi-process fleet on the SAME stream: worker processes tail
         # the wire over launch/transport.py and sync continuously during
         # decode — the transport's cost is measured against the in-process
-        # numbers above, not asserted away
+        # numbers above, not asserted away. Left out where the workers could
+        # not start (on an accelerator this process holds the chip)
         mp_rate = rates[-1]
-        serving_mp = {}
-        with fleet_lib.ProcessFleet(
-                stream_dir, n_workers=2, lags=(0, 2),
-                decode_budget=decode_budget, max_batch=max_batch,
-                prompt_len=prompt_len) as pfl:
-            pfl.sync()
-            reqs = fleet_lib.synthetic_requests(
-                n_requests, rate=mp_rate, prompt_len=prompt_len,
-                max_new_tokens=max_new)
-            mp_out = pfl.run(reqs)
-        key = f"multiproc_rate{mp_rate:g}"
-        metrics[key] = _percentiles_ns(
-            [r.latency_s for r in mp_out["requests"]])
-        serving_mp[key] = {
-            "rate_req_s": mp_rate, "qps": mp_out["qps"],
-            "p50_ms": mp_out["p50_ms"], "p99_ms": mp_out["p99_ms"],
-            "batches": mp_out["batches"],
-            "staleness_mean": mp_out["staleness_mean"],
-            "staleness_max": mp_out["staleness_max"],
-            "workers": len(mp_out["workers"]),
-            "restarts": mp_out["restarts"],
-            "mid_applied": mp_out["mid_applied"],
-        }
-        csv_row(f"serve_bench_multiproc_rate{mp_rate:g}",
-                metrics[key]["median_ns"] / 1e3,
-                f"qps={mp_out['qps']:.2f};p99_ms={mp_out['p99_ms']:.0f};"
-                f"staleness_max={mp_out['staleness_max']}")
+        serving_mp = None
+        why_not = fleet_lib.can_spawn_workers(2)
+        if why_not is not None:
+            print(f"multi-process phase left out: {why_not}")
+        else:
+            serving_mp = {}
+            with fleet_lib.ProcessFleet(
+                    stream_dir, n_workers=2, lags=(0, 2),
+                    decode_budget=decode_budget, max_batch=max_batch,
+                    prompt_len=prompt_len) as pfl:
+                pfl.sync()
+                reqs = fleet_lib.synthetic_requests(
+                    n_requests, rate=mp_rate, prompt_len=prompt_len,
+                    max_new_tokens=max_new)
+                mp_out = pfl.run(reqs)
+            key = f"multiproc_rate{mp_rate:g}"
+            metrics[key] = _percentiles_ns(
+                [r.latency_s for r in mp_out["requests"]])
+            serving_mp[key] = {
+                "rate_req_s": mp_rate, "qps": mp_out["qps"],
+                "p50_ms": mp_out["p50_ms"], "p99_ms": mp_out["p99_ms"],
+                "batches": mp_out["batches"],
+                "staleness_mean": mp_out["staleness_mean"],
+                "staleness_max": mp_out["staleness_max"],
+                "workers": len(mp_out["workers"]),
+                "restarts": mp_out["restarts"],
+                "mid_applied": mp_out["mid_applied"],
+            }
+            csv_row(f"serve_bench_multiproc_rate{mp_rate:g}",
+                    metrics[key]["median_ns"] / 1e3,
+                    f"qps={mp_out['qps']:.2f};p99_ms={mp_out['p99_ms']:.0f};"
+                    f"staleness_max={mp_out['staleness_max']}")
 
         # the remote tail on the SAME stream: the fleet subscribes through
         # tcp:// (TailServer RPC + local mirror) instead of the filesystem —
@@ -186,7 +195,8 @@ def run(tiny: bool = False) -> dict:
             metrics=metrics,
             speedup_vs_ref={"wire_bytes_vs_dense_f32": ratio_vs_dense})
         run_entry["serving"] = serving
-        run_entry["serving_multiproc"] = serving_mp
+        if serving_mp is not None:
+            run_entry["serving_multiproc"] = serving_mp
         run_entry["serving_remote"] = serving_remote
         run_entry["wire"] = {
             "wire_bytes_per_sync": wire_bytes,

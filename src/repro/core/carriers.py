@@ -23,9 +23,9 @@ The carriers:
   FusedPallasCarrier  dense wire + the whole EF21-SGD(M) client chain
                       (pre_compress → Block-TopK → post_compress) fused into ONE
                       HBM pass via kernels/ef_update.py (~3× on the memory-
-                      roofline term of the client update). Falls back to the
-                      Pallas interpreter off-TPU, and to the unfused dense plan
-                      for methods/compressors the kernel does not cover.
+                      roofline term of the client update). Interpreted on the
+                      CPU backend; the unfused dense plan runs for
+                      methods/compressors the kernel does not cover.
   QuantCarrier        block-quantized wire (kernels/quantize.py +
                       kernels/ref.py oracles): per-block absmax scale + int8
                       (``quant8``) or packed-uint4 (``quant4``) mantissas, for
@@ -106,15 +106,6 @@ Wire = Any
 DOWNLINK_FOLD = 1 << 20
 
 
-def axis_size(axis_name) -> jax.Array:
-    """Size of a shard_map/pmap axis, portable across JAX versions
-    (``jax.lax.axis_size`` only exists on newer releases)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def ring_all_gather(x: PyTree, axis_name, fn=None) -> PyTree:
     """``lax.all_gather`` rebuilt as a ring of n−1 ``ppermute`` steps.
 
@@ -130,7 +121,7 @@ def ring_all_gather(x: PyTree, axis_name, fn=None) -> PyTree:
     Degenerates to a no-op stack on a 1-device axis."""
     if fn is None:
         fn = lambda c: c                                 # noqa: E731
-    n = int(axis_size(axis_name))
+    n = int(jax.lax.axis_size(axis_name))
     if n == 1:
         return jax.tree_util.tree_map(lambda a: a[None], fn(x))
     perm = [(i, (i + 1) % n) for i in range(n)]
@@ -187,6 +178,15 @@ def scatter_blocks(vals: jax.Array, idx: jax.Array, *, nb: int, block: int,
     buf = jnp.zeros((nb, block), dtype)
     buf = buf.at[rows, idx.astype(jnp.int32)].set(vals)
     return buf.reshape(-1)[:d]
+
+
+def client_mean(vals: jax.Array, dp: int, d: int) -> jax.Array:
+    """Mean over clients of decoded block rows: ``vals`` is (dp·nb, block),
+    each client's nb rows in turn; returns the flat (d,) mean. The rows keep
+    their (nb, block) shape through the mean: a (dp, nb·block) view of a
+    full-width leaf makes the TPU compiler emit hundreds of MB of
+    relayout code and take minutes to compile it."""
+    return vals.reshape(dp, -1, vals.shape[-1]).mean(0).reshape(-1)[:d]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -402,7 +402,7 @@ class SparseBlockCarrier(Carrier):
         if axes is not None:
             n = 1
             for a in axes:                               # explicit wire
-                n = n * axis_size(a)
+                n = n * jax.lax.axis_size(a)
                 vals, idx = self._gather((vals, idx), a)
             vals = vals.reshape(-1, nb, kb)
             idx = idx.reshape(-1, nb, kb)
@@ -432,19 +432,13 @@ class FusedPallasCarrier(DenseCarrier):
     special case: v' = grad). The kernel needs a *static* momentum, so the
     plan degrades to 'dense' whenever η is traced (time-varying schedules).
 
-    ``interpret=None`` auto-selects the Pallas interpreter off-TPU so the
-    carrier runs (slowly but correctly) in CPU containers and under tests.
+    The kernel runs compiled on a TPU and in the Pallas interpreter on the
+    CPU backend (``kernels/backend.py::interpret``).
     """
 
     name: str = "fused"
-    interpret: Optional[bool] = None
 
     _LANES = 128                     # TPU vector lane width (f32)
-
-    def _interpret(self) -> bool:
-        if self.interpret is not None:
-            return self.interpret
-        return jax.default_backend() != "tpu"
 
     @classmethod
     def _kernel_geom(cls, comp, d: int) -> Tuple[int, int, int]:
@@ -488,6 +482,7 @@ class FusedPallasCarrier(DenseCarrier):
         tile rows, so no vmap-of-pallas_call is ever emitted).
         Returns (c_tree, new_state)."""
         from repro.kernels import ef_update as ef_kernel
+        from repro.kernels.backend import interpret
 
         comp = method.compressor
         if method.name == "ef21_sgd":
@@ -496,7 +491,7 @@ class FusedPallasCarrier(DenseCarrier):
         else:
             eta_f = float(eta) if eta is not None else float(method.eta)
             v_tree = state["v"]
-        interp = self._interpret()
+        interp = interpret()
 
         g_leaves, treedef = jax.tree_util.tree_flatten(state["g"])
         v_leaves = jax.tree_util.tree_leaves(v_tree)
@@ -580,7 +575,7 @@ class QuantCarrier(Carrier):
     dense payload are client→server bytes, not all-reduce bytes).
 
     The unbatched (shard_map) encode runs the Pallas codec
-    (kernels/quantize.py, interpreter off-TPU); the vmap runtimes run the
+    (kernels/quantize.py, interpreted on CPU); the vmap runtimes run the
     bit-compatible pure-jnp oracle (kernels/ref.py) so no vmap-of-pallas_call
     is ever emitted.
     """
@@ -637,16 +632,16 @@ class QuantCarrier(Carrier):
 
     def encode_local(self, comp, delta, rng=None):
         # client-local (shard_map) context: the Pallas codec quantizes the
-        # dense payload in one kernel pass (interpreter off-TPU); the sparse
+        # dense payload in one kernel pass (interpreted on CPU); the sparse
         # payload quantizes (nb, kb) value rows — lane-unfriendly tiles, so it
         # stays on the jnp oracle everywhere
         if self._sparse_ok(comp):
             return self.encode(comp, delta, rng)
         from repro.kernels import quantize as qz
+        from repro.kernels.backend import interpret
         c = comp(delta, rng).astype(jnp.float32)
-        interpret = jax.default_backend() != "tpu"
         return qz.block_quantize(c, block=self.qblock, bits=self.bits,
-                                 interpret=interpret)
+                                 interpret=interpret())
 
     def decode(self, comp, wire, *, d, dtype):
         # payload dispatch on the same predicate encode used — never on the
@@ -667,12 +662,13 @@ class QuantCarrier(Carrier):
 
     def decode_add(self, comp, wire, base, *, d, dtype):
         # dense payload on TPU: dequantize + integrate in ONE Pallas launch
-        # (kernels/fused_round.py::dequant_add). Off-TPU the default jnp
+        # (kernels/fused_round.py::dequant_add). On CPU the default jnp
         # expression already compiles to one fused XLA computation, and the
         # sparse payload's scatter decode has no tiled kernel — both take
         # the base-class path. Same math either way, so the h-integration
         # stays within float-compilation tolerance across backends.
-        if self._sparse_ok(comp) or jax.default_backend() != "tpu":
+        from repro.kernels.backend import interpret
+        if self._sparse_ok(comp) or interpret():
             return super().decode_add(comp, wire, base, d=d, dtype=dtype)
         from repro.kernels import fused_round as fr
         q, scales = wire
@@ -692,7 +688,7 @@ class QuantCarrier(Carrier):
                 # ``overlap`` the ring keeps the next permute in flight while
                 # this chunk dequantizes; the blocking path applies the same
                 # per-chunk decode to the gathered stack (same bits)
-                n = axis_size(axes[0])
+                n = jax.lax.axis_size(axes[0])
                 vals, idx = self._gather(
                     (q, scales, idx), axes[0],
                     fn=lambda w: (kref.block_dequantize_ref(
@@ -703,7 +699,7 @@ class QuantCarrier(Carrier):
                 if axes is not None:
                     n = 1
                     for a in axes:
-                        n = n * axis_size(a)
+                        n = n * jax.lax.axis_size(a)
                         q, scales, idx = self._gather((q, scales, idx), a)
                     q = q.reshape(-1, nb, q.shape[-1])
                     scales = scales.reshape(-1, nb)
@@ -726,7 +722,7 @@ class QuantCarrier(Carrier):
         vals = kref.block_dequantize_ref(
             q.reshape(dp_ * nbq, q.shape[-1]), scales.reshape(-1),
             bits=self.bits, cols=self.qblock)
-        return vals.reshape(dp_, -1)[:, :d].mean(0).astype(dtype)
+        return client_mean(vals, dp_, d).astype(dtype)
 
     # -- accounting ---------------------------------------------------------
     def wire_words(self, comp, d):
@@ -803,12 +799,6 @@ class FusedQuantCarrier(QuantCarrier):
     """
 
     name: str = "fused_quant8"
-    interpret: Optional[bool] = None
-
-    def _interpret(self) -> bool:
-        if self.interpret is not None:
-            return self.interpret
-        return jax.default_backend() != "tpu"
 
     def _fused_geom(self, comp, d: int) -> Tuple[int, int, int]:
         # the mega-kernel's launch geometry: selection geometry with
@@ -879,21 +869,17 @@ class FusedQuantCarrier(QuantCarrier):
 
     def decode_add(self, comp, wire, base, *, d, dtype):
         # the block-dense payload at the fused launch geometry runs the same
-        # one-launch dequantize+add kernel as the dense quant payload; an
-        # explicit ``interpret`` field (tests) or a real TPU selects the
-        # kernel, otherwise the default jnp expression (one fused XLA
-        # computation off-TPU) — bit-compatible within float-compilation
-        # tolerance either way
-        use_kernel = (self.interpret is not None
-                      or jax.default_backend() == "tpu")
-        if not self._sparse_ok(comp) or not use_kernel:
+        # one-launch dequantize+add kernel as the dense quant payload on TPU;
+        # on CPU the default jnp expression (one fused XLA computation) —
+        # bit-compatible within float-compilation tolerance either way
+        from repro.kernels.backend import interpret
+        if not self._sparse_ok(comp) or interpret():
             return super().decode_add(comp, wire, base, d=d, dtype=dtype)
         from repro.kernels import fused_round as fr
         q, scales = wire
         _, block, _ = self._fused_geom(comp, d)
         out = fr.dequant_add(q, scales, base.astype(jnp.float32), d=d,
-                             block=block, bits=self.bits,
-                             interpret=self._interpret())
+                             block=block, bits=self.bits, interpret=False)
         return out.astype(dtype)
 
     def aggregate(self, comp, wire, *, d, dtype, dp=None, axes=None):
@@ -910,7 +896,7 @@ class FusedQuantCarrier(QuantCarrier):
         vals = kref.block_dequantize_ref(
             q.reshape(dp_ * nb, q.shape[-1]), scales.reshape(-1),
             bits=self.bits, cols=block)
-        return vals.reshape(dp_, -1)[:, :d].mean(0).astype(dtype)
+        return client_mean(vals, dp_, d).astype(dtype)
 
     # -- accounting ---------------------------------------------------------
     def wire_words(self, comp, d):
@@ -944,6 +930,7 @@ class FusedQuantCarrier(QuantCarrier):
         Returns (msg_mean_tree, new_state)."""
         from repro.kernels import fused_round as fr
         from repro.kernels import ref as kref
+        from repro.kernels.backend import interpret
 
         comp = method.compressor
         if method.name == "ef21_sgd":
@@ -952,7 +939,7 @@ class FusedQuantCarrier(QuantCarrier):
         else:
             eta_f = float(eta) if eta is not None else float(method.eta)
             v_tree = state["v"]
-        interp = self._interpret()
+        interp = interpret()
 
         g_leaves, treedef = jax.tree_util.tree_flatten(state["g"])
         v_leaves = jax.tree_util.tree_leaves(v_tree)
@@ -978,7 +965,7 @@ class FusedQuantCarrier(QuantCarrier):
                 g2 = g2[:, :d].reshape(grad.shape)
                 vals = kref.block_dequantize_ref(q, scales, bits=self.bits,
                                                  cols=block)
-                msg = (vals.reshape(dpn, -1)[:, :d].mean(0)
+                msg = (client_mean(vals, dpn, d)
                        .reshape(grad.shape[1:]).astype(grad.dtype))
             else:
                 d = grad.size

@@ -226,8 +226,6 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
     This keeps compression 100% collective-free (no flatten-induced gathers) and
     makes the collective schedule ours rather than the SPMD partitioner's.
     """
-    from jax.experimental.shard_map import shard_map
-
     method = efc.method
     c_axes = efc.data_axes
     sched = efc.schedule
@@ -278,7 +276,7 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
         # this device's global client index over the client axes
         idx = 0
         for a in c_axes:
-            idx = idx * carrier_lib.axis_size(a) + jax.lax.axis_index(a)
+            idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         return idx
 
     def client_leg(grads_l, clients_l, rng_l, mask_l=None):
@@ -412,13 +410,13 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
             return out + ((new_pods,) if hops is not None else ())
 
         h_specs = state_specs.get("h", server_specs)
-        fn = shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(grads_specs, state_specs["clients"], server_specs,
                       h_specs, P()) + extra_specs,
             out_specs=(state_specs["clients"], server_specs, h_specs,
                        server_specs) + pod_out_specs,
-            check_rep=False)
+            check_vma=False)
         out = fn(
             grads, ef_state["clients"], ef_state["server"], ef_state["h"],
             rng, *extra_args)
@@ -441,11 +439,11 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
 
     out_specs = (state_specs["clients"], server_specs, server_specs) \
         + pod_out_specs
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(grads_specs, state_specs["clients"], server_specs, P())
         + extra_specs,
-        out_specs=out_specs, check_rep=False)
+        out_specs=out_specs, check_vma=False)
     out = fn(grads, ef_state["clients"], ef_state["server"], rng,
              *extra_args)
     new_clients, new_server = out[0], out[1]

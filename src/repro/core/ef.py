@@ -85,10 +85,19 @@ def tree_scale(a, s):
 
 
 def tree_lerp(a, b, eta):
-    """(1-eta)*a + eta*b — the Polyak momentum update, leaf-wise."""
-    return jax.tree_util.tree_map(
-        lambda x, y: ((1.0 - eta) * x.astype(jnp.float32)
-                      + eta * y.astype(jnp.float32)).astype(x.dtype), a, b)
+    """(1-eta)*a + eta*b — the Polyak momentum update, leaf-wise.
+
+    Written as a + eta*(b - a): ONE product, so a backend that contracts a
+    multiply-add into an FMA has only one way to do it. The two-product
+    form let XLA's CPU backend pick a different product to keep unrounded
+    in each fusion that recomputed the update, so the masked participation
+    round stored a momentum 1 ulp off the unmasked one. The Pallas kernels
+    and their oracles (kernels/ef_update.py, fused_round.py, ref.py) use
+    the same form, so the fused carriers keep dense's exact momentum."""
+    def one(x, y):
+        xf = x.astype(jnp.float32)
+        return (xf + eta * (y.astype(jnp.float32) - xf)).astype(x.dtype)
+    return jax.tree_util.tree_map(one, a, b)
 
 
 def tree_dim(tree) -> int:

@@ -28,7 +28,7 @@ def _ef_kernel(grad_ref, v_ref, g_ref, v_out, g_out, c_out, *,
     grad = grad_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)
-    v_new = (1.0 - eta) * v + eta * grad
+    v_new = v + eta * (grad - v)          # core/ef.py::tree_lerp's form
     delta = v_new - g
     ab = jnp.abs(delta)
     t = _bisect_threshold(ab, k)
@@ -44,13 +44,12 @@ def ef21_sgdm_update(grad: jax.Array, v: jax.Array, g: jax.Array, *,
                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """All inputs same shape. Returns (v', g', c)."""
     shape, d = grad.shape, grad.size
-    nb = -(-d // block)
+    rt, nb = _row_tiles(-(-d // block), block, rows_per_tile)
     pad = nb * block - d
 
     def prep(x):
         return jnp.pad(x.reshape(-1), (0, pad)).reshape(nb, block)
 
-    rt = _row_tiles(nb, block, rows_per_tile)
     spec = pl.BlockSpec((rt, block), lambda i: (i, 0))
     v_new, g_new, c = pl.pallas_call(
         functools.partial(_ef_kernel, eta=eta, k=k),

@@ -50,7 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.quantize import _row_tiles
+from repro.kernels.quantize import (_pad_rows, _row_tiles, pack_nibbles,
+                                    unpack_nibbles)
 from repro.kernels.topk_compress import _bisect_threshold
 
 
@@ -60,7 +61,7 @@ def _fused_uplink_kernel(grad_ref, v_ref, g_ref, v_out, g_out, q_out, s_out,
     v = v_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)
     # lines 6-7: momentum estimate, innovation, Block-TopK selection
-    v_new = (1.0 - eta) * v + eta * grad
+    v_new = v + eta * (grad - v)          # core/ef.py::tree_lerp's form
     delta = v_new - g
     ab = jnp.abs(delta)
     t = _bisect_threshold(ab, k)
@@ -80,8 +81,7 @@ def _fused_uplink_kernel(grad_ref, v_ref, g_ref, v_out, g_out, q_out, s_out,
     if bits == 8:
         q_out[...] = q.astype(jnp.int8)
     else:
-        u = (q + 8.0).astype(jnp.uint8).reshape(q.shape[0], -1, 2)
-        q_out[...] = (u[:, :, 0] << 4) | u[:, :, 1]
+        q_out[...] = pack_nibbles(q)
 
 
 def ef21_sgdm_topk_quant(grad: jax.Array, v: jax.Array, g: jax.Array, *,
@@ -99,33 +99,33 @@ def ef21_sgdm_topk_quant(grad: jax.Array, v: jax.Array, g: jax.Array, *,
     assert bits == 8 or block % 2 == 0, "uint4 packing needs an even block"
     shape, d = grad.shape, grad.size
     nb = -(-d // block)
-    pad = nb * block - d
+    rt, nbp = _row_tiles(nb, block, rows_per_tile)
+    pad = nbp * block - d
 
     def prep(x):
-        return jnp.pad(x.reshape(-1), (0, pad)).reshape(nb, block)
+        return jnp.pad(x.reshape(-1), (0, pad)).reshape(nbp, block)
 
-    rt = _row_tiles(nb, block, rows_per_tile)
     qcols = block if bits == 8 else block // 2
     qdtype = jnp.int8 if bits == 8 else jnp.uint8
     spec = pl.BlockSpec((rt, block), lambda i: (i, 0))
     v_new, g_new, q, scales = pl.pallas_call(
         functools.partial(_fused_uplink_kernel, eta=eta, k=k, bits=bits),
-        grid=(nb // rt,),
+        grid=(nbp // rt,),
         in_specs=[spec, spec, spec],
         out_specs=(spec, spec,
                    pl.BlockSpec((rt, qcols), lambda i: (i, 0)),
                    pl.BlockSpec((rt, 1), lambda i: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((nb, block), v.dtype),
-                   jax.ShapeDtypeStruct((nb, block), g.dtype),
-                   jax.ShapeDtypeStruct((nb, qcols), qdtype),
-                   jax.ShapeDtypeStruct((nb, 1), jnp.float32)),
+        out_shape=(jax.ShapeDtypeStruct((nbp, block), v.dtype),
+                   jax.ShapeDtypeStruct((nbp, block), g.dtype),
+                   jax.ShapeDtypeStruct((nbp, qcols), qdtype),
+                   jax.ShapeDtypeStruct((nbp, 1), jnp.float32)),
         interpret=interpret,
     )(prep(grad), prep(v), prep(g))
 
     def unprep(x):
         return x.reshape(-1)[:d].reshape(shape)
 
-    return unprep(v_new), unprep(g_new), q, scales.reshape(-1)
+    return unprep(v_new), unprep(g_new), q[:nb], scales[:nb].reshape(-1)
 
 
 def _dequant_add_kernel(q_ref, s_ref, b_ref, o_ref, *, bits: int,
@@ -134,10 +134,7 @@ def _dequant_add_kernel(q_ref, s_ref, b_ref, o_ref, *, bits: int,
     if bits == 8:
         vals = q_ref[...].astype(jnp.float32)
     else:
-        p = q_ref[...]
-        hi = (p >> 4).astype(jnp.float32) - 8.0
-        lo = (p & 0xF).astype(jnp.float32) - 8.0
-        vals = jnp.stack([hi, lo], axis=-1).reshape(p.shape[0], -1)
+        vals = unpack_nibbles(q_ref[...])
     dec = vals * scale[:, None]
     if alpha != 1.0:
         dec = alpha * dec
@@ -157,18 +154,17 @@ def dequant_add(q: jax.Array, scales: jax.Array, base: jax.Array, *, d: int,
     """
     assert bits in (8, 4), bits
     shape = base.shape
-    nb = q.shape[0]
+    rt, nbp = _row_tiles(q.shape[0], block, rows_per_tile)
     bb = jnp.pad(base.reshape(-1).astype(jnp.float32),
-                 (0, nb * block - d)).reshape(nb, block)
-    rt = _row_tiles(nb, block, rows_per_tile)
+                 (0, nbp * block - d)).reshape(nbp, block)
     out = pl.pallas_call(
         functools.partial(_dequant_add_kernel, bits=bits, alpha=alpha),
-        grid=(nb // rt,),
+        grid=(nbp // rt,),
         in_specs=[pl.BlockSpec((rt, q.shape[1]), lambda i: (i, 0)),
                   pl.BlockSpec((rt, 1), lambda i: (i, 0)),
                   pl.BlockSpec((rt, block), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rt, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, block), base.dtype),
+        out_shape=jax.ShapeDtypeStruct((nbp, block), base.dtype),
         interpret=interpret,
-    )(q, scales.reshape(-1, 1), bb)
+    )(_pad_rows(q, nbp), _pad_rows(scales.reshape(-1, 1), nbp), bb)
     return out.reshape(-1)[:d].reshape(shape)

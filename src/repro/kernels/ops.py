@@ -1,9 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True`` (the Pallas
-interpreter runs the kernel body op-for-op — the correctness target validated
-against ref.py). On TPU, ``interpret=False`` compiles to Mosaic. The model code
-selects between these wrappers and the pure-JAX paths via ``use_pallas``.
+On the CPU backend the kernels run in the Pallas interpreter (the kernel body
+op-for-op — the correctness target validated against ref.py); on a TPU they
+compile to Mosaic. ``kernels/backend.py::interpret`` makes that choice.
 """
 from __future__ import annotations
 
@@ -13,14 +12,11 @@ from typing import Tuple
 import jax
 
 from repro.kernels import ef_update as _ef
+from repro.kernels.backend import interpret as _interpret
 from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_round as _fr
 from repro.kernels import quantize as _qz
 from repro.kernels import topk_compress as _tk
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))

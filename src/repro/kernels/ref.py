@@ -43,7 +43,7 @@ def ef21_sgdm_update_ref(grad: jax.Array, v: jax.Array, g: jax.Array, *,
     """Fused EF21-SGDM client update (Algorithm 1 lines 6–8) with Block-TopK:
        v' = (1−η)v + η·grad;  c = BlockTopK(v' − g);  g' = g + c.
     Returns (v', g', c)."""
-    v_new = (1.0 - eta) * v + eta * grad
+    v_new = v + eta * (grad - v)          # core/ef.py::tree_lerp's form
     c = block_topk_ref(v_new - g, block, k)
     return v_new, g + c, c
 
@@ -90,8 +90,10 @@ def block_quantize_ref(x: jax.Array, bits: int
         return q.astype(jnp.int8), scale
     if q.shape[1] % 2:
         q = jnp.pad(q, ((0, 0), (0, 1)))
-    u = (q + 8.0).astype(jnp.uint8).reshape(q.shape[0], -1, 2)
-    return (u[:, :, 0] << 4) | u[:, :, 1], scale
+    # even/odd column slices, never a trailing axis of 2: on TPU such an
+    # axis is padded to a whole 128-lane tile, 64x the bytes
+    u = (q + 8.0).astype(jnp.uint8)
+    return (u[:, 0::2] << 4) | u[:, 1::2], scale
 
 
 def block_dequantize_ref(q: jax.Array, scales: jax.Array, *, bits: int,
@@ -100,7 +102,10 @@ def block_dequantize_ref(q: jax.Array, scales: jax.Array, *, bits: int,
     if bits == 8:
         vals = q.astype(jnp.float32)
     else:
-        hi = (q >> 4).astype(jnp.float32) - 8.0
-        lo = (q & 0xF).astype(jnp.float32) - 8.0
-        vals = jnp.stack([hi, lo], axis=-1).reshape(q.shape[0], -1)[:, :cols]
+        # each byte repeated over its two slots, then the high nibble in even
+        # slots and the low one in odd slots (no trailing axis of 2 — see
+        # block_quantize_ref)
+        pairs = jnp.repeat(q.astype(jnp.int32), 2, axis=1)
+        shift = jnp.where(jnp.arange(pairs.shape[1]) % 2 == 0, 4, 0)
+        vals = ((pairs >> shift) & 0xF)[:, :cols].astype(jnp.float32) - 8.0
     return vals * scales.astype(jnp.float32)[:, None]

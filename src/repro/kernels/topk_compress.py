@@ -53,9 +53,8 @@ def block_topk(x: jax.Array, *, block: int = 1024, k: int = 16,
                rows_per_tile: int = 8, interpret: bool = False) -> jax.Array:
     """x: any shape; flattened, padded to blocks, sparsified, reshaped back."""
     shape, d = x.shape, x.size
-    nb = -(-d // block)
+    rt, nb = _row_tiles(-(-d // block), block, rows_per_tile)
     xb = jnp.pad(x.reshape(-1), (0, nb * block - d)).reshape(nb, block)
-    rt = _row_tiles(nb, block, rows_per_tile)
 
     out = pl.pallas_call(
         functools.partial(_topk_kernel, k=k),

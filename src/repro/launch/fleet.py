@@ -31,6 +31,8 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import glob
+import os
 import time
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -434,6 +436,57 @@ def _summary(done: List[Request], batches: int, **extra) -> Dict[str, Any]:
 # the multi-process fleet
 # ---------------------------------------------------------------------------
 
+# the device nodes through which a process holds a TPU chip: /dev/accel*,
+# or one vfio group per chip on v5e and later
+ACCELERATOR_NODES = ("/dev/accel[0-9]*", "/dev/vfio/[0-9]*")
+
+
+def accelerator_nodes() -> List[str]:
+    """This host's accelerator device nodes; empty on a CPU-only host."""
+    return sorted(p for pat in ACCELERATOR_NODES for p in glob.glob(pat))
+
+
+def held_nodes(nodes: Sequence[str]) -> List[str]:
+    """The nodes among ``nodes`` this process has open — how it holds a
+    chip once its JAX backend has started on it."""
+    held = set()
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            target = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:
+            continue                  # closed since the listing
+        if target in nodes:
+            held.add(target)
+    return sorted(held)
+
+
+def can_spawn_workers(n_workers: int) -> Optional[str]:
+    """None when ``n_workers`` replica worker processes can each start
+    their JAX backend; else why not. A chip belongs to one process: a
+    worker that needs an accelerator this process holds, or one
+    accelerator between several workers, fails at start-up or waits on
+    the chip forever. Workers inherit this environment, so with
+    ``JAX_PLATFORMS=cpu`` every one runs on its own CPU backend, as it
+    does on a host with no accelerator."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return None
+    nodes = accelerator_nodes()
+    if not nodes:
+        return None
+    held = held_nodes(nodes)
+    if held:
+        return (f"this process holds the accelerator ({', '.join(held)}) "
+                "its replica worker processes would need; serve in-process "
+                "(Fleet) or start ProcessFleet from a process that has not "
+                "used the accelerator")
+    if n_workers > 1:
+        return (f"{n_workers} replica worker processes would share this "
+                "host's accelerator, which only one process can hold; run "
+                "one worker, serve in-process (Fleet), or set "
+                "JAX_PLATFORMS=cpu")
+    return None
+
+
 class ProcessFleet:
     """N replica WORKER PROCESSES on one wire stream (DESIGN.md §12): each
     worker is a ``python -m repro.launch.replica_worker`` subprocess running
@@ -460,6 +513,9 @@ class ProcessFleet:
         lags = list(lags) if lags is not None else [0] * n_workers
         if len(lags) != n_workers:
             raise ValueError(f"{n_workers} workers but {len(lags)} lags")
+        reason = can_spawn_workers(n_workers)
+        if reason is not None:
+            raise RuntimeError(reason)
         self.workers = [
             worker_lib.WorkerHandle(
                 str(stream), name=f"w{i}", lag=lags[i],

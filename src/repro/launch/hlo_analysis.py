@@ -32,13 +32,8 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 def cost_analysis_dict(compiled) -> Dict:
-    """Normalize ``Compiled.cost_analysis()`` across JAX versions: older
-    releases return a one-element **list** of per-module dicts, newer ones the
-    dict itself (and it may be None/empty for some backends)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if cost else {}
+    """``Compiled.cost_analysis()`` as a dict (the backend may return None)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 _SHAPE_TOKEN = re.compile(r"(\w+)\[([\d,]*)\]")

@@ -11,23 +11,10 @@ import jax
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Version-portable ``jax.make_mesh``: newer JAX wants explicit
-    ``axis_types`` (we always use Auto — shard_map handles Manual itself);
-    older JAX (< 0.5) has neither ``jax.sharding.AxisType`` nor the kwarg."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (shard_map handles Manual
+    itself)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where it exists (JAX ≥ 0.6); the ``Mesh``
-    context manager itself on older releases. Use as ``with mesh_context(m):``."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # production mesh geometry (also consumed by benchmarks/roofline.py, which

@@ -126,8 +126,10 @@ def main(argv=None) -> None:
                     help="heartbeat interval in seconds (0 = off)")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
     from repro.launch import fleet as fleet_lib  # defer the jax-heavy import
 
+    compile_cache.enable()
     rep = fleet_lib.ServeReplica(args.stream, name=args.name, lag=args.lag,
                                  bootstrap_step=args.bootstrap_step)
     _emit({"type": "ready", "name": rep.name, "step": rep.step,

@@ -111,6 +111,8 @@ def main(argv=None) -> None:
                          "during decode")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     if args.serve_stream:
         _fleet_main(args)
         return

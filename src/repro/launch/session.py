@@ -307,7 +307,7 @@ class Session:
     def mesh_context(self):
         """``with sess.mesh_context():`` — the spec's mesh as the ambient
         mesh (re-entrant; lower()/serve()/train() enter it themselves)."""
-        return mesh_lib.mesh_context(self.mesh)
+        return jax.set_mesh(self.mesh)
 
     def _ambient(self):
         # the 1-device smoke path keeps jit's default placement (bit-compat
@@ -362,6 +362,11 @@ class Session:
         def loss_fn(p, b):
             return model_lib.train_loss(cfg, p, b)
 
+        # the step DONATES params, opt_state and ef_state: the new state is
+        # written over the old one, which is what lets a full-width run hold
+        # one copy of its state in device memory instead of two. A caller
+        # that steps the jitted function directly passes the state the
+        # previous call returned, never the arguments it gave that call.
         if mesh.size > 1:
             grads_specs = sh._spec_map(
                 lambda s: sh.P(sh.client_axis(mesh, plan), *s),
@@ -372,9 +377,11 @@ class Session:
                                              hops=efc.hops)
             step_fn = jax.jit(dist.make_train_step(
                 loss_fn, efc, opt, n, mesh=mesh, grads_specs=grads_specs,
-                state_specs=state_specs))
+                state_specs=state_specs), donate_argnums=(0, 1, 2))
         else:
-            step_fn = jax.jit(dist.make_train_step(loss_fn, efc, opt, n))
+            grads_specs = state_specs = None
+            step_fn = jax.jit(dist.make_train_step(loss_fn, efc, opt, n),
+                              donate_argnums=(0, 1, 2))
 
         rng = jax.random.PRNGKey(spec.seed)
 
@@ -386,13 +393,35 @@ class Session:
             return {"params": params, "opt_state": opt.init(params),
                     "ef_state": ef_state}
 
+        # one compiled program: op-by-op, a full-width init spends over a
+        # minute compiling and dispatching each primitive on a TPU. Its
+        # outputs are distinct buffers even where the init shares one array
+        # between leaves (v⁰ = g⁰, h⁰ = g⁰), as the donating step needs: a
+        # buffer donated twice in one call is refused
+        if mesh.size > 1:
+            # the batch is split over the client axes, so each chip computes
+            # its own clients' g⁰, and their state is born there
+            # (state_specs); params and the optimizer state are replicated,
+            # as the step returns them. No chip ever holds every client's
+            # state, and the first compile of the step serves every step
+            named = lambda s: sh.NamedSharding(mesh, s)  # noqa: E731
+            b_specs = sh.batch_pspecs(cfg, mesh, "train", spec.global_batch)
+            init_fn = jax.jit(init_state, in_shardings=(
+                {k: named(s) for k, s in b_specs.items()},), out_shardings={
+                "params": named(sh.P()), "opt_state": named(sh.P()),
+                "ef_state": jax.tree_util.tree_map(
+                    named, state_specs,
+                    is_leaf=lambda x: isinstance(x, sh.P))})
+        else:
+            init_fn = jax.jit(init_state)
         b0 = pipe_lib.with_prefix_embeds(cfg, pipe.batch(0))
         with self._ambient():
             state = jax.eval_shape(init_state, b0) if template \
-                else init_state(b0)
+                else init_fn(b0)
         self._tr = {
             "efc": efc, "opt": opt, "pipe": pipe, "loss_fn": loss_fn,
-            "step_fn": step_fn, "rng": rng, **state,
+            "step_fn": step_fn, "rng": rng, "grads_specs": grads_specs,
+            "state_specs": state_specs, **state,
         }
         return self._tr
 
@@ -412,8 +441,23 @@ class Session:
     def step_fn(self):
         """The jitted production train step
         ``(params, opt_state, ef_state, batch, rng, step) → (…, metrics)`` —
-        benchmarks time this directly against a fixed batch."""
+        benchmarks time this directly against a fixed batch. It donates its
+        three state arguments: feed each call the state the last one
+        returned."""
         return self._ensure_train()["step_fn"]
+
+    @property
+    def efc(self) -> dist.EFConfig:
+        """The EFConfig the step runs its EF round with."""
+        return self._ensure_train()["efc"]
+
+    @property
+    def shard_specs(self):
+        """``(grads_specs, state_specs)``: how the multi-device step lays
+        out the per-client gradients and the EF state; ``(None, None)`` on
+        one device."""
+        tr = self._ensure_train()
+        return tr["grads_specs"], tr["state_specs"]
 
     def batch_for(self, step: int) -> PyTree:
         """The (frontend-padded) global batch the pipeline yields for
@@ -426,8 +470,10 @@ class Session:
         """Advance exactly one training step; returns the step metrics.
         The unit benchmarks time (benchmarks/kernel_bench.py)."""
         tr = self._ensure_train()
-        h_prev = tr["ef_state"].get("h") if self._publisher is not None \
-            else None
+        # the publisher re-encodes this round's wire from the PRE-step h,
+        # which the donating step overwrites: keep a copy of it on the device
+        h = tr["ef_state"].get("h") if self._publisher is not None else None
+        h_prev = None if h is None else jax.tree_util.tree_map(jnp.copy, h)
         with self._ambient():
             batch = self.batch_for(self.step)
             (tr["params"], tr["opt_state"], tr["ef_state"], m) = tr["step_fn"](
@@ -563,7 +609,7 @@ class Session:
         shard_of = lambda tree: jax.tree_util.tree_map(
             lambda s: s.sharding, tree)
 
-        with mesh_lib.mesh_context(mesh):
+        with jax.set_mesh(mesh):
             # placed params are cached on the params VERSION — the single
             # source of truth every mutation path bumps (step_once,
             # restore_from, set_serve_params) — so a serving loop never

@@ -64,8 +64,10 @@ def main(argv=None) -> None:
         multiproc.distributed_init(args.coordinator, args.num_processes,
                                    args.process_id)
 
+    from repro.launch import compile_cache
     from repro.launch.session import Session  # defer the jax-heavy import
 
+    compile_cache.enable()
     if args.resume:
         if not spec.ckpt_dir:
             ap.error("--resume needs --ckpt-dir")

@@ -447,7 +447,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
                   "server": rep}
         if "pods" in st:
             sspecs["pods"] = {"t": pod, "b": pod}
-        with mesh_lib.mesh_context(mesh):
+        with jax.set_mesh(mesh):
             return jax.jit(lambda g, s: D.ef_round_sharded(
                 efc, g, s, None, mesh, gspecs, sspecs))(grads_t, st)
 

@@ -2,6 +2,7 @@
 
 The parametrized differential tests always run; the hypothesis fuzzers engage
 wherever hypothesis is installed (CI via requirements-dev.txt)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -221,3 +222,17 @@ def test_bisection_threshold_exactness():
         cnt = (np.asarray(ab) >= t[:, None]).sum(1)
         assert (cnt >= k).all()
         np.testing.assert_allclose(t, kth, rtol=2e-4)   # 26 bisection iters
+
+
+def test_kernels_interpret_only_on_cpu(monkeypatch):
+    """One switch decides interpreted vs compiled kernels: the CPU
+    interprets, a TPU compiles, any other backend is an error — never a
+    silent interpreter on an accelerator."""
+    from repro.kernels import backend
+
+    assert backend.interpret() is True              # tests run on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert backend.interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        backend.interpret()

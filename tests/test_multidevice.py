@@ -35,7 +35,7 @@ SCRIPT = textwrap.dedent("""
         efc = D.EFConfig(method=method, carrier=carrier, data_axes=("data",))
         st = D.init_ef_state(efc, params, dp, init_grads=grads_t)
         g_ref, st_ref = D.ef_round(efc, grads_t, st, None)
-        with mesh_lib.mesh_context(mesh):
+        with jax.set_mesh(mesh):
             g_sm, st_sm = jax.jit(lambda g, s: D.ef_round_sharded(
                 efc, g, s, None, mesh, gspecs, sspecs))(grads_t, st)
         np.testing.assert_allclose(np.asarray(g_sm["w"]),
@@ -54,7 +54,7 @@ SCRIPT = textwrap.dedent("""
         efc = D.EFConfig(method=m_ht, carrier=carrier, data_axes=("data",))
         st = D.init_ef_state(efc, params, dp, init_grads=grads_t)
         g_ref, _ = D.ef_round(efc, grads_t, st, None)
-        with mesh_lib.mesh_context(mesh):
+        with jax.set_mesh(mesh):
             g_sm, _ = jax.jit(lambda g, s: D.ef_round_sharded(
                 efc, g, s, None, mesh, gspecs, sspecs))(grads_t, st)
         np.testing.assert_allclose(np.asarray(g_sm["w"]),
@@ -69,7 +69,7 @@ SCRIPT = textwrap.dedent("""
     efc = D.EFConfig(method=m_abs, carrier="dense", data_axes=("data",))
     st = D.init_ef_state(efc, params, dp, init_grads=grads_t)
     g_ref, _ = D.ef_round(efc, grads_t, st, None)
-    with mesh_lib.mesh_context(mesh):
+    with jax.set_mesh(mesh):
         g_sm, _ = jax.jit(lambda g, s: D.ef_round_sharded(
             efc, g, s, None, mesh, gspecs, sspecs))(grads_t, st)
     np.testing.assert_allclose(np.asarray(g_sm["w"]), np.asarray(g_ref["w"]),
@@ -104,7 +104,7 @@ SCRIPT = textwrap.dedent("""
                                 for k in st["clients"]},
                     "server": {"w": P(None, None)},
                     "h": {"w": P(None, None)}}
-        with mesh_lib.mesh_context(mesh):
+        with jax.set_mesh(mesh):
             g_sm, st_sm = jax.jit(lambda g, s: D.ef_round_sharded(
                 efc, g, s, None, mesh, gspecs, sspecs_d))(grads_t, st)
         np.testing.assert_allclose(np.asarray(g_sm["w"]),

@@ -20,7 +20,6 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import functools
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core import carriers as carrier_lib
     from repro.core import compressors as C, distributed as D, ef
@@ -30,7 +29,7 @@ SCRIPT = textwrap.dedent("""
     dp = 4
 
     # --- ring_all_gather == lax.all_gather (the bit-identity that makes the
-    # overlapped transport an anchor-preserving rewrite). check_rep=False:
+    # overlapped transport an anchor-preserving rewrite). check_vma=False:
     # ppermute-based gathers defeat shard_map's static replication inference.
     x = jnp.arange(4 * 6, dtype=jnp.float32).reshape(4, 6)
 
@@ -39,8 +38,9 @@ SCRIPT = textwrap.dedent("""
         ref = jax.lax.all_gather(xs, "data")
         return ring, ref
 
-    sm = shard_map(plain, mesh=mesh, in_specs=P("data", None),
-                   out_specs=(P(None, None), P(None, None)), check_rep=False)
+    sm = jax.shard_map(plain, mesh=mesh, in_specs=P("data", None),
+                       out_specs=(P(None, None), P(None, None)),
+                       check_vma=False)
     ring, ref = sm(x)
     np.testing.assert_array_equal(np.asarray(ring), np.asarray(ref))
 
@@ -49,8 +49,9 @@ SCRIPT = textwrap.dedent("""
         ref = jax.lax.all_gather(xs * 2.0, "data")
         return ring, ref
 
-    sm = shard_map(with_fn, mesh=mesh, in_specs=P("data", None),
-                   out_specs=(P(None, None), P(None, None)), check_rep=False)
+    sm = jax.shard_map(with_fn, mesh=mesh, in_specs=P("data", None),
+                       out_specs=(P(None, None), P(None, None)),
+                       check_vma=False)
     ring, ref = sm(x)
     np.testing.assert_array_equal(np.asarray(ring), np.asarray(ref))
     print("ring_all_gather OK")
@@ -82,7 +83,7 @@ SCRIPT = textwrap.dedent("""
             sspecs = {"clients": {k: {"w": P("data", None, None)}
                                   for k in st["clients"]},
                       "server": {"w": P(None, None)}}
-            with mesh_lib.mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 outs[overlap] = jax.jit(
                     functools.partial(D.ef_round_sharded, efc, mesh=mesh,
                                       grads_specs=gspecs,

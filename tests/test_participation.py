@@ -264,7 +264,7 @@ def test_sampled_fraction_one_bit_matches_full_sharded(carrier):
                       down_carrier="quant4", down_compressor=DOWN_BTK)
     sampled = dataclasses.replace(full, participation=FULL_1)
     mesh, grads, st, gspecs, sspecs = _sharded_setup(full)
-    with mesh_lib.mesh_context(mesh):
+    with jax.set_mesh(mesh):
         g0, s0 = jax.jit(lambda g, s: D.ef_round_sharded(
             full, g, s, None, mesh, gspecs, sspecs))(grads, st)
         g1, s1 = jax.jit(lambda g, s, t: D.ef_round_sharded(
@@ -280,7 +280,7 @@ def test_sharded_sampled_cohort_matches_vmap_sampled():
     efc = D.EFConfig(method=method, carrier="sparse", data_axes=("data",),
                      participation=HALF)
     mesh, grads, st, gspecs, sspecs = _sharded_setup(efc)
-    with mesh_lib.mesh_context(mesh):
+    with jax.set_mesh(mesh):
         g_sh, s_sh = jax.jit(lambda g, s, t: D.ef_round_sharded(
             efc, g, s, None, mesh, gspecs, sspecs, step=t))(
             grads, st, jnp.int32(1))

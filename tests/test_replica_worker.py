@@ -166,3 +166,35 @@ def test_process_fleet_serves_and_survives_kill(wire):
         killer.cancel()
         assert sorted(r.rid for r in out["requests"]) == list(range(6))
         assert out["restarts"] >= 1
+
+
+def test_process_fleet_refuses_a_held_or_shared_chip(monkeypatch, tmp_path):
+    """One process per chip: ProcessFleet raises BEFORE spawning workers
+    that would need an accelerator this process holds, or one accelerator
+    between several of them — such a worker fails or hangs on the chip.
+    A host with no accelerator, or workers forced onto the CPU, start any
+    number of workers."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(fleet_lib, "accelerator_nodes", lambda: [])
+    assert fleet_lib.can_spawn_workers(2) is None   # CPU-only host
+
+    monkeypatch.setattr(fleet_lib, "accelerator_nodes",
+                        lambda: ["/dev/vfio/0"])
+    monkeypatch.setattr(fleet_lib, "held_nodes", lambda nodes: list(nodes))
+    with pytest.raises(RuntimeError, match="this process holds"):
+        fleet_lib.ProcessFleet(str(tmp_path), n_workers=1)
+    monkeypatch.setattr(fleet_lib, "held_nodes", lambda nodes: [])
+    assert "would share" in fleet_lib.can_spawn_workers(2)
+    assert fleet_lib.can_spawn_workers(1) is None   # one worker takes it
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert fleet_lib.can_spawn_workers(2) is None   # every worker on CPU
+
+
+def test_held_nodes_reads_this_process_open_files(tmp_path):
+    """How a held chip is seen: its device node among this process's open
+    files (a plain file stands in for the node here)."""
+    node = tmp_path / "accel0"
+    node.write_text("")
+    assert fleet_lib.held_nodes([str(node)]) == []
+    with open(node):
+        assert fleet_lib.held_nodes([str(node)]) == [str(node)]

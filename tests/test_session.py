@@ -216,3 +216,28 @@ def test_make_method_rejects_unknown_kwargs():
         session_lib.make_method(RunSpec(method_kw={"bogus_knob": 1}))
     with pytest.raises(ValueError, match="compressor_kw"):
         session_lib.make_compressor(RunSpec(compressor_kw={"nope": 2}))
+
+
+def test_compile_cache_honours_env_else_one_fixed_path(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins when set (the code sets nothing);
+    otherwise the cache sits at one fixed, git-ignored path in the
+    checkout — the path is part of the cache key, so it never moves."""
+    import os
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.enable() == compile_cache.CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == compile_cache.CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        compilation_cache.reset_cache()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.CACHE_DIR == os.path.join(root, ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

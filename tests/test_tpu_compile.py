@@ -1,0 +1,93 @@
+"""The main-path Pallas kernels compile for a TPU v5e at smollm-360m leaf
+sizes. Nothing runs: the TPU compiler in the installed libtpu compiles for
+a described chip with none attached, so Mosaic refuses here what it would
+refuse on the chip (unsupported casts, unaligned tiles, too much VMEM) —
+faults the interpret-mode tests cannot see. Each compiled program must
+hold the kernel as a ``tpu_custom_call``.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library at a time, and under pytest-xdist
+every worker imports this file."""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import ef_update, fused_round, quantize, topk_compress
+
+# smollm-360m leaves: one MLP projection (d_model × d_ff) and the embedding
+LEAVES = {"mlp": (2560, 960), "embed": (49152, 960)}
+BLOCK, K, QBLOCK, ETA = 1024, 51, 256, 0.1   # Block-TopK ratio 0.05
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no TPU compiler
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernel(name: str, shape):
+    """(fn, [(shape, dtype), ...]) for one main-path kernel on a leaf."""
+    d = shape[0] * shape[1]
+    nb = -(-d // BLOCK)
+    f32 = jnp.float32
+    leaf = [(shape, f32)] * 3
+    if name == "ef21_sgdm_update":
+        return functools.partial(ef_update.ef21_sgdm_update, eta=ETA,
+                                 block=BLOCK, k=K), leaf
+    if name.startswith("ef21_sgdm_topk_quant"):
+        bits = int(name[-1])
+        return functools.partial(fused_round.ef21_sgdm_topk_quant, eta=ETA,
+                                 block=BLOCK, k=K, bits=bits), leaf
+    if name.startswith("dequant_add"):
+        bits = int(name[-1])
+        q = ((nb, BLOCK if bits == 8 else BLOCK // 2),
+             jnp.int8 if bits == 8 else jnp.uint8)
+        return functools.partial(fused_round.dequant_add, d=d, block=BLOCK,
+                                 bits=bits), [q, ((nb,), f32), (shape, f32)]
+    if name.startswith("block_quantize"):
+        return functools.partial(quantize.block_quantize, block=QBLOCK,
+                                 bits=int(name[-1])), [(shape, f32)]
+    if name == "block_topk":
+        return functools.partial(topk_compress.block_topk, block=BLOCK,
+                                 k=K), [(shape, f32)]
+    raise KeyError(name)
+
+
+KERNELS = ["ef21_sgdm_update", "ef21_sgdm_topk_quant8",
+           "ef21_sgdm_topk_quant4", "dequant_add8", "dequant_add4",
+           "block_quantize8", "block_quantize4", "block_topk"]
+
+
+@pytest.mark.parametrize("leaf", sorted(LEAVES))
+@pytest.mark.parametrize("name", KERNELS)
+def test_kernel_compiles_for_v5e(name, leaf, one_chip):
+    fn, args = _kernel(name, LEAVES[leaf])
+    specs = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+             for s, dt in args]
+    compiled = jax.jit(fn).lower(*specs).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        f"{name} at {LEAVES[leaf]} compiled without its Mosaic kernel"
