@@ -96,6 +96,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import compressors as comp_lib
+from repro.telemetry import EF_AGGREGATE, EF_CLIENT
 
 PyTree = Any
 Wire = Any
@@ -945,6 +946,8 @@ class FusedQuantCarrier(QuantCarrier):
         v_leaves = jax.tree_util.tree_leaves(v_tree)
         grad_leaves = jax.tree_util.tree_leaves(grads)
 
+        # the pads, the launch and the client state under EF_CLIENT; the
+        # decode and the mean over clients under EF_AGGREGATE
         v_out, g_out, msg_out = [], [], []
         for grad, v, g in zip(grad_leaves, v_leaves, g_leaves):
             if batched:
@@ -958,38 +961,43 @@ class FusedQuantCarrier(QuantCarrier):
                 def prep(x):
                     return jnp.pad(x.reshape(dpn, d), ((0, 0), (0, pad)))
 
-                v2, g2, q, scales = fr.ef21_sgdm_topk_quant(
-                    prep(grad), prep(v), prep(g), eta=eta_f, block=block,
-                    k=kb, bits=self.bits, interpret=interp)
-                v2 = v2[:, :d].reshape(grad.shape)
-                g2 = g2[:, :d].reshape(grad.shape)
-                vals = kref.block_dequantize_ref(q, scales, bits=self.bits,
-                                                 cols=block)
-                msg = (client_mean(vals, dpn, d)
-                       .reshape(grad.shape[1:]).astype(grad.dtype))
+                with jax.named_scope(EF_CLIENT):
+                    v2, g2, q, scales = fr.ef21_sgdm_topk_quant(
+                        prep(grad), prep(v), prep(g), eta=eta_f,
+                        block=block, k=kb, bits=self.bits, interpret=interp)
+                    v2 = v2[:, :d].reshape(grad.shape)
+                    g2 = g2[:, :d].reshape(grad.shape)
+                with jax.named_scope(EF_AGGREGATE):
+                    vals = kref.block_dequantize_ref(
+                        q, scales, bits=self.bits, cols=block)
+                    msg = (client_mean(vals, dpn, d)
+                           .reshape(grad.shape[1:]).astype(grad.dtype))
             else:
                 d = grad.size
                 nb, block, kb = self._fused_geom(comp, d)
-                v2, g2, q, scales = fr.ef21_sgdm_topk_quant(
-                    grad, v, g, eta=eta_f, block=block, k=kb,
-                    bits=self.bits, interpret=interp)
-                vals = kref.block_dequantize_ref(q, scales, bits=self.bits,
-                                                 cols=block)
-                dec = vals.reshape(-1)[:d].astype(jnp.float32)
-                msg = (jax.lax.pmean(dec, axes)      # dense-payload rule:
-                       .reshape(grad.shape)          # dequant THEN psum
-                       .astype(grad.dtype))
+                with jax.named_scope(EF_CLIENT):
+                    v2, g2, q, scales = fr.ef21_sgdm_topk_quant(
+                        grad, v, g, eta=eta_f, block=block, k=kb,
+                        bits=self.bits, interpret=interp)
+                with jax.named_scope(EF_AGGREGATE):
+                    vals = kref.block_dequantize_ref(
+                        q, scales, bits=self.bits, cols=block)
+                    dec = vals.reshape(-1)[:d].astype(jnp.float32)
+                    msg = (jax.lax.pmean(dec, axes)  # dense-payload rule:
+                           .reshape(grad.shape)      # dequant THEN psum
+                           .astype(grad.dtype))
             v_out.append(v2)
             g_out.append(g2)
             msg_out.append(msg)
 
         unf = lambda ls: jax.tree_util.tree_unflatten(treedef, ls)  # noqa: E731
         msg_mean = unf(msg_out)
-        g_new = method._cast(unf(g_out))
-        if method.name == "ef21_sgd":
-            new_state = {"g": g_new}
-        else:
-            new_state = {"v": method._cast(unf(v_out)), "g": g_new}
+        with jax.named_scope(EF_CLIENT):
+            g_new = method._cast(unf(g_out))
+            if method.name == "ef21_sgd":
+                new_state = {"g": g_new}
+            else:
+                new_state = {"v": method._cast(unf(v_out)), "g": g_new}
         return msg_mean, new_state
 
 
@@ -1000,15 +1008,19 @@ class FusedQuantCarrier(QuantCarrier):
 def wire_round_batched(carrier: Carrier, comp, deltas: PyTree, dp: int
                        ) -> Tuple[PyTree, PyTree]:
     """encode → local_c → aggregate per leaf, clients on a leading axis (vmap
-    runtimes). Returns (c_tree, msg_mean_tree)."""
+    runtimes). Returns (c_tree, msg_mean_tree); the encode runs under
+    EF_CLIENT, the aggregation under EF_AGGREGATE."""
     dleaves, dtree = jax.tree_util.tree_flatten(deltas)
     c_leaves, agg_leaves = [], []
     for leaf in dleaves:
         d = int(leaf[0].size)
         flat = leaf.reshape(dp, d)
-        wire = jax.vmap(lambda x: carrier.encode(comp, x))(flat)
-        c_loc = jax.vmap(lambda x, w: carrier.local_c(comp, x, w))(flat, wire)
-        agg = carrier.aggregate(comp, wire, d=d, dtype=leaf.dtype, dp=dp)
+        with jax.named_scope(EF_CLIENT):
+            wire = jax.vmap(lambda x: carrier.encode(comp, x))(flat)
+            c_loc = jax.vmap(lambda x, w: carrier.local_c(comp, x, w))(
+                flat, wire)
+        with jax.named_scope(EF_AGGREGATE):
+            agg = carrier.aggregate(comp, wire, d=d, dtype=leaf.dtype, dp=dp)
         c_leaves.append(c_loc.reshape(leaf.shape))
         agg_leaves.append(agg.reshape(leaf.shape[1:]))
     return (jax.tree_util.tree_unflatten(dtree, c_leaves),
@@ -1018,16 +1030,20 @@ def wire_round_batched(carrier: Carrier, comp, deltas: PyTree, dp: int
 def wire_round_local(carrier: Carrier, comp, deltas: PyTree,
                      axes: Tuple[str, ...], rng=None) -> Tuple[PyTree, PyTree]:
     """encode → local_c → aggregate per leaf, client-local inside shard_map
-    (explicit named-axis collectives). Returns (c_tree, msg_mean_tree)."""
+    (explicit named-axis collectives). Returns (c_tree, msg_mean_tree);
+    the encode runs under EF_CLIENT, the aggregation under EF_AGGREGATE."""
     dleaves, dtree = jax.tree_util.tree_flatten(deltas)
     c_leaves, agg_leaves = [], []
     for leaf in dleaves:
         flat = leaf.reshape(-1)
-        wire = carrier.encode_local(comp, flat, rng)
-        c_leaves.append(carrier.local_c(comp, flat, wire).reshape(leaf.shape))
-        agg_leaves.append(carrier.aggregate(
-            comp, wire, d=leaf.size, dtype=leaf.dtype, axes=axes)
-            .reshape(leaf.shape))
+        with jax.named_scope(EF_CLIENT):
+            wire = carrier.encode_local(comp, flat, rng)
+            c_leaves.append(
+                carrier.local_c(comp, flat, wire).reshape(leaf.shape))
+        with jax.named_scope(EF_AGGREGATE):
+            agg_leaves.append(carrier.aggregate(
+                comp, wire, d=leaf.size, dtype=leaf.dtype, axes=axes)
+                .reshape(leaf.shape))
     return (jax.tree_util.tree_unflatten(dtree, c_leaves),
             jax.tree_util.tree_unflatten(dtree, agg_leaves))
 

@@ -56,6 +56,8 @@ from repro.core import ef as ef_lib
 from repro.core import hierarchy as hier_lib
 from repro.core import participation as part_lib
 from repro.core import schedule as sched_lib
+from repro.telemetry import (DOWNLINK, EF_AGGREGATE, EF_CLIENT, MODEL,
+                             OPTIMIZER)
 
 PyTree = Any
 
@@ -287,6 +289,11 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
         # collective fold only the sampled cohort (C(0) = 0 exactly)
         mask_m = None if mask_l is None else mask_l[client_index()]
 
+        # scopes as in ef_round: EF_CLIENT for the update and encode,
+        # EF_AGGREGATE for the decode and the collective
+        pmean = jax.named_scope(EF_AGGREGATE)(
+            lambda t: jax.tree_util.tree_map(
+                lambda m: jax.lax.pmean(m, intra_axes), t))
         if sched is not None:
             # grouped engine: one wire (and one aggregation collective) per
             # group, each on its group's carrier/compressor
@@ -294,11 +301,11 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
                 sched, method, g, cl, intra_axes, rng_l, eta,
                 overlap=efc.overlap, mask=mask_m)
         elif plan == "fused":
-            c_tree, new_cl = carrier.fused_update(method, g, cl, eta=eta)
-            if mask_m is not None:
-                c_tree = part_lib.apply_mask(mask_m, c_tree)
-            msg_mean = jax.tree_util.tree_map(
-                lambda c: jax.lax.pmean(c, intra_axes), c_tree)
+            with jax.named_scope(EF_CLIENT):
+                c_tree, new_cl = carrier.fused_update(method, g, cl, eta=eta)
+                if mask_m is not None:
+                    c_tree = part_lib.apply_mask(mask_m, c_tree)
+            msg_mean = pmean(c_tree)
         elif plan == "fused_wire":
             if mask_m is not None:
                 # unreachable behind the spec/build construction errors: the
@@ -311,29 +318,33 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
             msg_mean, new_cl = carrier.fused_wire_round(
                 method, g, cl, eta=eta, axes=intra_axes)
         elif plan == "wire":
-            deltas, ctx = method.pre_compress(g, cl, eta=eta)
-            if mask_m is not None:
-                deltas = part_lib.apply_mask(mask_m, deltas)
+            with jax.named_scope(EF_CLIENT):
+                deltas, ctx = method.pre_compress(g, cl, eta=eta)
+                if mask_m is not None:
+                    deltas = part_lib.apply_mask(mask_m, deltas)
             c_tree, msg_mean = carrier_lib.wire_round_local(
                 carrier, method.compressor, deltas, intra_axes, rng_l)
-            _, new_cl = method.post_compress(c_tree, ctx)
+            with jax.named_scope(EF_CLIENT):
+                _, new_cl = method.post_compress(c_tree, ctx)
         else:
             # dense plan: aggregate the method's actual MESSAGE (for
             # wire_is_msg=False methods msg ≠ c, e.g. Abs ships γ·c), and go
             # through method.update so methods without a two-phase API
             # (neolithic, ideal) also run on the sharded path
-            msg, new_cl = method.update(g, cl, rng_l, eta=eta)
-            if mask_m is not None:
-                msg = part_lib.apply_mask(mask_m, msg)
-            msg_mean = jax.tree_util.tree_map(
-                lambda m: jax.lax.pmean(m, intra_axes), msg)
+            with jax.named_scope(EF_CLIENT):
+                msg, new_cl = method.update(g, cl, rng_l, eta=eta)
+                if mask_m is not None:
+                    msg = part_lib.apply_mask(mask_m, msg)
+            msg_mean = pmean(msg)
         if mask_m is not None:
             # Bells & Whistles: delta methods fold (1/n)Σ_S as-is, absolute
             # methods rescale to the cohort mean; non-sampled clients keep
             # their ENTIRE state tree (gᵢ, momentum, …) bit-frozen
-            msg_mean = part_lib.rescale_message(method, msg_mean, n_total,
-                                                m_cohort)
-            new_cl = part_lib.freeze_tree(mask_m, new_cl, cl)
+            with jax.named_scope(EF_AGGREGATE):
+                msg_mean = part_lib.rescale_message(method, msg_mean,
+                                                    n_total, m_cohort)
+            with jax.named_scope(EF_CLIENT):
+                new_cl = part_lib.freeze_tree(mask_m, new_cl, cl)
         return ex(new_cl), msg_mean
 
     def fold_client(rng_l):
@@ -392,20 +403,23 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
                 else jax.random.fold_in(rng_l, DOWNLINK_FOLD)
             new_cl, msg_mean = client_leg(
                 grads_l, clients_l, fold_client(rng_l), mask_l)
-            if hops is not None:
-                msg_mean, new_pods = pod_leg(msg_mean, pods_l, rng_l)
-            new_server = ef_lib.server_step(method, server_l, msg_mean)
+            with jax.named_scope(EF_AGGREGATE):
+                if hops is not None:
+                    msg_mean, new_pods = pod_leg(msg_mean, pods_l, rng_l)
+                new_server = ef_lib.server_step(method, server_l, msg_mean)
             # every device runs the same encode of the replicated-in-value
             # new_server (that IS the broadcast — the encoded wire is what
             # travels) and the same decode its client would run. Sampling
             # composes for free: h is server-side, so a client absent for k
             # rounds still integrated every broadcast and re-enters in sync
-            if sched is not None:
-                g_est, h_new = sched_lib.downlink_round_grouped(
-                    sched, new_server, h_l, r_down)
-            else:
-                g_est, h_new = ef_lib.downlink_sync(
-                    down_carrier, down_comp, new_server, h_l, rng=r_down)
+            with jax.named_scope(DOWNLINK):
+                if sched is not None:
+                    g_est, h_new = sched_lib.downlink_round_grouped(
+                        sched, new_server, h_l, r_down)
+                else:
+                    g_est, h_new = ef_lib.downlink_sync(
+                        down_carrier, down_comp, new_server, h_l,
+                        rng=r_down)
             out = (new_cl, new_server, h_new, g_est)
             return out + ((new_pods,) if hops is not None else ())
 
@@ -431,9 +445,10 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
         pods_l, mask_l = split_rest(rest)
         new_cl, msg_mean = client_leg(
             grads_l, clients_l, fold_client(rng_l), mask_l)
-        if hops is not None:
-            msg_mean, new_pods = pod_leg(msg_mean, pods_l, rng_l)
-        new_server = ef_lib.server_step(method, server_l, msg_mean)
+        with jax.named_scope(EF_AGGREGATE):
+            if hops is not None:
+                msg_mean, new_pods = pod_leg(msg_mean, pods_l, rng_l)
+            new_server = ef_lib.server_step(method, server_l, msg_mean)
         out = (new_cl, new_server, msg_mean)
         return out + ((new_pods,) if hops is not None else ())
 
@@ -484,18 +499,23 @@ def ef_round(efc: EFConfig, grads: PyTree, ef_state: Dict,
                 "fused_wire carriers aggregate all clients inside the "
                 "mega-kernel — there is no per-pod message to re-aggregate "
                 "(guarded at spec/build construction)")
-    agg = (lambda t: hier_lib.pod_mean(t, hops.pods)) if want_pods \
-        else (lambda t: jax.tree_util.tree_map(lambda m: m.mean(0), t))
+    agg = jax.named_scope(EF_AGGREGATE)(
+        (lambda t: hier_lib.pod_mean(t, hops.pods)) if want_pods
+        else (lambda t: jax.tree_util.tree_map(lambda m: m.mean(0), t)))
 
+    # scopes: the clients' update, compression and encode run under
+    # EF_CLIENT, the decode and the mean over clients under EF_AGGREGATE
+    # (the schedule's and the carriers' shared legs scope themselves)
     if efc.schedule is not None:
         msg_mean, new_clients = sched_lib.round_batched(
             efc.schedule, method, grads, clients, dp, rng, eta, mask=mask,
             pods=hops.pods if want_pods else 1)
     elif plan == "fused":
-        c_tree, new_clients = carrier.fused_update(
-            method, grads, clients, eta=eta, batched=True)
-        if mask is not None:
-            c_tree = part_lib.apply_mask(mask, c_tree)
+        with jax.named_scope(EF_CLIENT):
+            c_tree, new_clients = carrier.fused_update(
+                method, grads, clients, eta=eta, batched=True)
+            if mask is not None:
+                c_tree = part_lib.apply_mask(mask, c_tree)
         msg_mean = agg(c_tree)
     elif plan == "fused_wire":
         if mask is not None:
@@ -506,57 +526,66 @@ def ef_round(efc: EFConfig, grads: PyTree, ef_state: Dict,
         msg_mean, new_clients = carrier.fused_wire_round(
             method, grads, clients, eta=eta, batched=True, dp=dp)
     elif plan == "wire":
-        deltas, ctxs = jax.vmap(
-            lambda g, s: method.pre_compress(g, s, eta=eta))(grads, clients)
-        if mask is not None:
-            # zero-masked wires: C(0) = 0 exactly, so the carrier's own
-            # aggregation folds only the sampled cohort
-            deltas = part_lib.apply_mask(mask, deltas)
+        with jax.named_scope(EF_CLIENT):
+            deltas, ctxs = jax.vmap(
+                lambda g, s: method.pre_compress(g, s, eta=eta))(
+                grads, clients)
+            if mask is not None:
+                # zero-masked wires: C(0) = 0 exactly, so the carrier's own
+                # aggregation folds only the sampled cohort
+                deltas = part_lib.apply_mask(mask, deltas)
         c_tree, wire_mean = carrier_lib.wire_round_batched(
             carrier, method.compressor, deltas, dp)
         # non-trivial hops pod-mean the per-client messages (local_c IS the
         # decode of what traveled); the unused global aggregate is DCE'd
         msg_mean = agg(c_tree) if want_pods else wire_mean
-        _, new_clients = jax.vmap(method.post_compress)(c_tree, ctxs)
+        with jax.named_scope(EF_CLIENT):
+            _, new_clients = jax.vmap(method.post_compress)(c_tree, ctxs)
     else:
         def upd(g, s, r):
             return method.update(g, s, r, eta=eta)
-        if rngs is None:
-            msgs, new_clients = jax.vmap(lambda g, s: upd(g, s, None))(
-                grads, clients)
-        else:
-            msgs, new_clients = jax.vmap(upd)(grads, clients, rngs)
-        if mask is not None:
-            msgs = part_lib.apply_mask(mask, msgs)
+        with jax.named_scope(EF_CLIENT):
+            if rngs is None:
+                msgs, new_clients = jax.vmap(lambda g, s: upd(g, s, None))(
+                    grads, clients)
+            else:
+                msgs, new_clients = jax.vmap(upd)(grads, clients, rngs)
+            if mask is not None:
+                msgs = part_lib.apply_mask(mask, msgs)
         msg_mean = agg(msgs)
 
     if mask is not None:
         # Bells & Whistles: delta methods fold (1/n)Σ_S as-is, absolute
         # methods rescale to the cohort mean; non-sampled clients keep
         # their ENTIRE state tree (gᵢ, momentum, …) bit-frozen
-        msg_mean = part_lib.rescale_message(
-            method, msg_mean, dp, efc.participation.cohort_size(dp))
-        new_clients = part_lib.freeze_tree(mask, new_clients, clients)
-    if want_pods:
-        new_pods, new_server = hier_lib.round_pods_batched(
-            hops, efc.schedule, method, msg_mean, ef_state["pods"], server,
-            rng)
-    else:
-        new_server = ef_lib.server_step(method, server, msg_mean)
-    new_state = {"clients": new_clients, "server": new_server}
-    if hops is not None:
-        new_state["pods"] = new_pods if want_pods else \
-            hier_lib.trivial_bookkeeping(method, ef_state["pods"], msg_mean)
+        with jax.named_scope(EF_AGGREGATE):
+            msg_mean = part_lib.rescale_message(
+                method, msg_mean, dp, efc.participation.cohort_size(dp))
+        with jax.named_scope(EF_CLIENT):
+            new_clients = part_lib.freeze_tree(mask, new_clients, clients)
+    with jax.named_scope(EF_AGGREGATE):
+        if want_pods:
+            new_pods, new_server = hier_lib.round_pods_batched(
+                hops, efc.schedule, method, msg_mean, ef_state["pods"],
+                server, rng)
+        else:
+            new_server = ef_lib.server_step(method, server, msg_mean)
+        new_state = {"clients": new_clients, "server": new_server}
+        if hops is not None:
+            new_state["pods"] = new_pods if want_pods else \
+                hier_lib.trivial_bookkeeping(method, ef_state["pods"],
+                                             msg_mean)
     if not efc.has_downlink:
         return new_server, new_state
     r_down = None if rng is None else jax.random.fold_in(rng, DOWNLINK_FOLD)
-    if efc.schedule is not None:
-        g_est, h_new = sched_lib.downlink_round_grouped(
-            efc.schedule, new_server, ef_state["h"], r_down)
-    else:
-        g_est, h_new = ef_lib.downlink_sync(
-            carrier_lib.make(efc.down_carrier), efc.down_comp(), new_server,
-            ef_state["h"], rng=r_down)
+    with jax.named_scope(DOWNLINK):
+        if efc.schedule is not None:
+            g_est, h_new = sched_lib.downlink_round_grouped(
+                efc.schedule, new_server, ef_state["h"], r_down)
+        else:
+            g_est, h_new = ef_lib.downlink_sync(
+                carrier_lib.make(efc.down_carrier), efc.down_comp(),
+                new_server, ef_state["h"], rng=r_down)
     new_state["h"] = h_new
     return g_est, new_state
 
@@ -575,7 +604,11 @@ def make_train_step(loss_fn: Callable, efc: EFConfig, optimizer, dp: int,
     from repro.optim.optimizer import apply_updates
 
     def train_step(params, opt_state, ef_state, batch, rng, step):
-        loss, aux, grads = per_client_value_and_grad(loss_fn, params, batch, dp)
+        # the scope sits outside vmap and autodiff, so forward, backward and
+        # remat ops all carry it
+        with jax.named_scope(MODEL):
+            loss, aux, grads = per_client_value_and_grad(
+                loss_fn, params, batch, dp)
         r_comp = jax.random.fold_in(rng, 1)
         if mesh is not None and grads_specs is not None:
             g_est, ef_state = ef_round_sharded(
@@ -584,10 +617,15 @@ def make_train_step(loss_fn: Callable, efc: EFConfig, optimizer, dp: int,
         else:
             g_est, ef_state = ef_round(efc, grads, ef_state, r_comp, eta=eta,
                                        step=step)
-        updates, opt_state = optimizer.update(g_est, opt_state, params, step)
-        params = apply_updates(params, updates)
-        metrics = {"loss": loss,
-                   "g_norm": jnp.sqrt(ef_lib.tree_norm_sq(g_est))}
+        with jax.named_scope(OPTIMIZER):
+            updates, opt_state = optimizer.update(g_est, opt_state, params,
+                                                  step)
+            params = apply_updates(params, updates)
+        # the estimate's norm reads what the server step writes, and XLA
+        # fuses the two: it runs under the aggregation's scope
+        with jax.named_scope(EF_AGGREGATE):
+            g_norm = jnp.sqrt(ef_lib.tree_norm_sq(g_est))
+        metrics = {"loss": loss, "g_norm": g_norm}
         return params, opt_state, ef_state, metrics
 
     return train_step

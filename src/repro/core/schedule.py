@@ -43,6 +43,7 @@ from repro.core import carriers as carrier_lib
 from repro.core import compressors as comp_lib
 from repro.core import ef as ef_lib
 from repro.core import participation as part_lib
+from repro.telemetry import EF_AGGREGATE, EF_CLIENT
 
 PyTree = Any
 
@@ -383,6 +384,7 @@ def round_batched(schedule: CompressionSchedule, method, grads: PyTree,
     if pods > 1 and dp % pods:
         raise ValueError(f"pods={pods} must divide the client count {dp}")
 
+    @jax.named_scope(EF_AGGREGATE)
     def agg(leaves_list):
         if pods > 1:
             m = dp // pods
@@ -391,12 +393,15 @@ def round_batched(schedule: CompressionSchedule, method, grads: PyTree,
                 leaves_list)
         return jax.tree_util.tree_map(lambda c: c.mean(0), leaves_list)
 
+    # scopes as in distributed.ef_round: EF_CLIENT for the update and
+    # encode, EF_AGGREGATE for the decode and the mean over clients
     def leg(m_g, carrier, plan, grads_g, states_g, r_g):
         if plan == "fused":
-            c_tree, new_st = carrier.fused_update(
-                m_g, grads_g, states_g, eta=eta, batched=True)
-            if mask is not None:
-                c_tree = part_lib.apply_mask(mask, c_tree)
+            with jax.named_scope(EF_CLIENT):
+                c_tree, new_st = carrier.fused_update(
+                    m_g, grads_g, states_g, eta=eta, batched=True)
+                if mask is not None:
+                    c_tree = part_lib.apply_mask(mask, c_tree)
             return agg(c_tree), new_st
         if plan == "fused_wire":
             if mask is not None:
@@ -411,28 +416,31 @@ def round_batched(schedule: CompressionSchedule, method, grads: PyTree,
             return carrier.fused_wire_round(
                 m_g, grads_g, states_g, eta=eta, batched=True, dp=dp)
         if plan == "wire":
-            deltas, ctxs = jax.vmap(
-                lambda g, s, m=m_g: m.pre_compress(g, s, eta=eta))(
-                grads_g, states_g)
-            if mask is not None:
-                deltas = part_lib.apply_mask(mask, deltas)
+            with jax.named_scope(EF_CLIENT):
+                deltas, ctxs = jax.vmap(
+                    lambda g, s, m=m_g: m.pre_compress(g, s, eta=eta))(
+                    grads_g, states_g)
+                if mask is not None:
+                    deltas = part_lib.apply_mask(mask, deltas)
             c_tree, agg_g = carrier_lib.wire_round_batched(
                 carrier, m_g.compressor, deltas, dp)
-            _, new_st = jax.vmap(m_g.post_compress)(c_tree, ctxs)
+            with jax.named_scope(EF_CLIENT):
+                _, new_st = jax.vmap(m_g.post_compress)(c_tree, ctxs)
             # per-pod means of the decoded client messages; the global
             # aggregate the carrier built is unused and DCE'd under jit
             return (agg(c_tree) if pods > 1 else agg_g), new_st
-        if r_g is None:
-            msgs, new_st = jax.vmap(
-                lambda g, s, m=m_g: m.update(g, s, None, eta=eta))(
-                grads_g, states_g)
-        else:
-            rngs = jax.random.split(r_g, dp)
-            msgs, new_st = jax.vmap(
-                lambda g, s, r, m=m_g: m.update(g, s, r, eta=eta))(
-                grads_g, states_g, rngs)
-        if mask is not None:
-            msgs = part_lib.apply_mask(mask, msgs)
+        with jax.named_scope(EF_CLIENT):
+            if r_g is None:
+                msgs, new_st = jax.vmap(
+                    lambda g, s, m=m_g: m.update(g, s, None, eta=eta))(
+                    grads_g, states_g)
+            else:
+                rngs = jax.random.split(r_g, dp)
+                msgs, new_st = jax.vmap(
+                    lambda g, s, r, m=m_g: m.update(g, s, r, eta=eta))(
+                    grads_g, states_g, rngs)
+            if mask is not None:
+                msgs = part_lib.apply_mask(mask, msgs)
         return agg(msgs), new_st
 
     return _grouped_round(schedule, method, grads, states, rng, eta, leg)
@@ -449,14 +457,17 @@ def round_local(schedule: CompressionSchedule, method, grads: PyTree,
     non-sampled device's contribution before its collective — the
     freeze/rescale postlude stays at the CALLER. Returns
     ``(msg_mean, new_states)``."""
+    pmean = jax.named_scope(EF_AGGREGATE)(
+        lambda t: jax.tree_util.tree_map(lambda m: jax.lax.pmean(m, axes), t))
+
     def leg(m_g, carrier, plan, grads_g, states_g, r_g):
         if plan == "fused":
-            c_tree, new_st = carrier.fused_update(
-                m_g, grads_g, states_g, eta=eta)
-            if mask is not None:
-                c_tree = part_lib.apply_mask(mask, c_tree)
-            return jax.tree_util.tree_map(
-                lambda c: jax.lax.pmean(c, axes), c_tree), new_st
+            with jax.named_scope(EF_CLIENT):
+                c_tree, new_st = carrier.fused_update(
+                    m_g, grads_g, states_g, eta=eta)
+                if mask is not None:
+                    c_tree = part_lib.apply_mask(mask, c_tree)
+            return pmean(c_tree), new_st
         if plan == "fused_wire":
             if mask is not None:
                 # unreachable behind the spec/build construction errors
@@ -465,18 +476,20 @@ def round_local(schedule: CompressionSchedule, method, grads: PyTree,
             return carrier.fused_wire_round(
                 m_g, grads_g, states_g, eta=eta, axes=axes)
         if plan == "wire":
-            deltas, ctx = m_g.pre_compress(grads_g, states_g, eta=eta)
-            if mask is not None:
-                deltas = part_lib.apply_mask(mask, deltas)
+            with jax.named_scope(EF_CLIENT):
+                deltas, ctx = m_g.pre_compress(grads_g, states_g, eta=eta)
+                if mask is not None:
+                    deltas = part_lib.apply_mask(mask, deltas)
             c_tree, agg_g = carrier_lib.wire_round_local(
                 carrier, m_g.compressor, deltas, axes, r_g)
-            _, new_st = m_g.post_compress(c_tree, ctx)
+            with jax.named_scope(EF_CLIENT):
+                _, new_st = m_g.post_compress(c_tree, ctx)
             return agg_g, new_st
-        msg, new_st = m_g.update(grads_g, states_g, r_g, eta=eta)
-        if mask is not None:
-            msg = part_lib.apply_mask(mask, msg)
-        return jax.tree_util.tree_map(
-            lambda m: jax.lax.pmean(m, axes), msg), new_st
+        with jax.named_scope(EF_CLIENT):
+            msg, new_st = m_g.update(grads_g, states_g, r_g, eta=eta)
+            if mask is not None:
+                msg = part_lib.apply_mask(mask, msg)
+        return pmean(msg), new_st
 
     return _grouped_round(schedule, method, grads, states, rng, eta, leg,
                           overlap=overlap)

@@ -59,6 +59,7 @@ def ef21_sgdm_update(grad: jax.Array, v: jax.Array, g: jax.Array, *,
         out_shape=tuple(jax.ShapeDtypeStruct((nb, block), x.dtype)
                         for x in (v, g, g)),
         interpret=interpret,
+        name="ef_update",
     )(prep(grad), prep(v), prep(g))
 
     def unprep(x):

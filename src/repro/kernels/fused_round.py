@@ -120,6 +120,7 @@ def ef21_sgdm_topk_quant(grad: jax.Array, v: jax.Array, g: jax.Array, *,
                    jax.ShapeDtypeStruct((nbp, qcols), qdtype),
                    jax.ShapeDtypeStruct((nbp, 1), jnp.float32)),
         interpret=interpret,
+        name="ef21_sgdm_uplink",
     )(prep(grad), prep(v), prep(g))
 
     def unprep(x):
@@ -166,5 +167,6 @@ def dequant_add(q: jax.Array, scales: jax.Array, base: jax.Array, *, d: int,
         out_specs=pl.BlockSpec((rt, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nbp, block), base.dtype),
         interpret=interpret,
+        name="dequant_add",
     )(_pad_rows(q, nbp), _pad_rows(scales.reshape(-1, 1), nbp), bb)
     return out.reshape(-1)[:d].reshape(shape)

@@ -151,6 +151,7 @@ def block_quantize(x: jax.Array, *, block: int = 256, bits: int = 8,
         out_shape=(jax.ShapeDtypeStruct((nbp, qcols), qdtype),
                    jax.ShapeDtypeStruct((nbp, 1), jnp.float32)),
         interpret=interpret,
+        name="block_quantize",
     )(xb)
     return q[:nb], scales[:nb].reshape(-1)
 
@@ -169,5 +170,6 @@ def block_dequantize(q: jax.Array, scales: jax.Array, *, d: int,
         out_specs=pl.BlockSpec((rt, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nbp, block), jnp.float32),
         interpret=interpret,
+        name="block_dequantize",
     )(_pad_rows(q, nbp), _pad_rows(scales.reshape(-1, 1), nbp))
     return out.reshape(-1)[:d]

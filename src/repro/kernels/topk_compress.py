@@ -63,5 +63,6 @@ def block_topk(x: jax.Array, *, block: int = 1024, k: int = 16,
         out_specs=pl.BlockSpec((rt, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block), x.dtype),
         interpret=interpret,
+        name="topk_compress",
     )(xb)
     return out.reshape(-1)[:d].reshape(shape)

@@ -37,6 +37,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.checkpoint import checkpoint as ckpt_lib
 from repro.configs import base as cb
 from repro.core import compressors as comp_lib
@@ -273,6 +274,7 @@ class Session:
             state_sharding=spec.state_sharding,
             ef_state_dtype=spec.ef_state_dtype)
         self.step = 0                       # the data cursor: pipeline.batch(step)
+        self._stepped = False               # a compile after this is a recompile
         self.history: List[Dict[str, float]] = []
         self._tr: Optional[Dict[str, Any]] = None
         self._last_saved_step: Optional[int] = None
@@ -416,8 +418,11 @@ class Session:
             init_fn = jax.jit(init_state)
         b0 = pipe_lib.with_prefix_embeds(cfg, pipe.batch(0))
         with self._ambient():
-            state = jax.eval_shape(init_state, b0) if template \
-                else init_fn(b0)
+            if template:
+                state = jax.eval_shape(init_state, b0)
+            else:
+                with telemetry.span("init"), telemetry.phase("init"):
+                    state = init_fn(b0)
         self._tr = {
             "efc": efc, "opt": opt, "pipe": pipe, "loss_fn": loss_fn,
             "step_fn": step_fn, "rng": rng, "grads_specs": grads_specs,
@@ -468,28 +473,39 @@ class Session:
     # -------------------------------------------------------------- training
     def step_once(self) -> Dict[str, jax.Array]:
         """Advance exactly one training step; returns the step metrics.
-        The unit benchmarks time (benchmarks/kernel_bench.py)."""
+        The unit benchmarks time (benchmarks/kernel_bench.py). Runs under
+        the host spans ``repro.step`` > ``repro.batch``, ``repro.dispatch``,
+        ``repro.publish``, and counts its compiles in phase ``step``."""
         tr = self._ensure_train()
-        # the publisher re-encodes this round's wire from the PRE-step h,
-        # which the donating step overwrites: keep a copy of it on the device
-        h = tr["ef_state"].get("h") if self._publisher is not None else None
-        h_prev = None if h is None else jax.tree_util.tree_map(jnp.copy, h)
-        with self._ambient():
-            batch = self.batch_for(self.step)
-            (tr["params"], tr["opt_state"], tr["ef_state"], m) = tr["step_fn"](
-                tr["params"], tr["opt_state"], tr["ef_state"], batch,
-                jax.random.fold_in(tr["rng"], self.step), self.step)
-        self.step += 1
-        self._params_version += 1
-        if self._publisher is not None:
-            # publish this round's downlink wire (verified bit-exact against
-            # the step's own h before anything hits the log)
-            self._publisher.publish(
-                self.step, tr["ef_state"]["server"], h_prev,
-                tr["ef_state"].get("h"))
-            if self._bootstrap_every \
-                    and self.step % self._bootstrap_every == 0:
-                self._write_bootstrap()
+        with telemetry.step_span(self.step):
+            # the publisher re-encodes this round's wire from the PRE-step
+            # h, which the donating step overwrites: keep a copy of it on
+            # the device
+            h = None if self._publisher is None else tr["ef_state"].get("h")
+            h_prev = None if h is None else jax.tree_util.tree_map(
+                jnp.copy, h)
+            with self._ambient():
+                with telemetry.span("batch"):
+                    batch = self.batch_for(self.step)
+                with telemetry.span("dispatch"), telemetry.phase(
+                        "step", step=self.step, warm=self._stepped):
+                    (tr["params"], tr["opt_state"], tr["ef_state"],
+                     m) = tr["step_fn"](
+                        tr["params"], tr["opt_state"], tr["ef_state"], batch,
+                        jax.random.fold_in(tr["rng"], self.step), self.step)
+            self._stepped = True
+            self.step += 1
+            self._params_version += 1
+            if self._publisher is not None:
+                # publish this round's downlink wire (verified bit-exact
+                # against the step's own h before anything hits the log)
+                with telemetry.span("publish"):
+                    self._publisher.publish(
+                        self.step, tr["ef_state"]["server"], h_prev,
+                        tr["ef_state"].get("h"))
+                    if self._bootstrap_every \
+                            and self.step % self._bootstrap_every == 0:
+                        self._write_bootstrap()
         return m
 
     def train(self, steps: int, log_every: int = 10, verbose: bool = False
@@ -503,9 +519,18 @@ class Session:
         self._ensure_train()
         new: List[Dict[str, float]] = []
         t0, start = time.time(), self.step
+        seen = len(telemetry.recompiles())
         while self.step < steps:
             m = self.step_once()
             step = self.step - 1
+            if verbose:
+                # a compile after the first step is a recompile: a new
+                # shape or dtype reached the step
+                recompiled = telemetry.recompiles()
+                for r in recompiled[seen:]:
+                    print(f"recompiled {r['fun']} at step {r['step']} "
+                          f"({r['seconds']:.2f}s)", flush=True)
+                seen = len(recompiled)
             if (log_every and step % log_every == 0) or step == steps - 1:
                 rec = {"step": step, "loss": float(m["loss"]),
                        "g_norm": float(m["g_norm"])}

@@ -127,3 +127,67 @@ def test_shardmap_ef_round_matches_vmap_path():
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600)
     assert "MULTIDEVICE_OK" in out.stdout, out.stdout + out.stderr
+
+
+SCOPES_SCRIPT = textwrap.dedent("""
+    import os, re
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from repro import telemetry as T
+    from repro.core import compressors as C, distributed as D, ef
+    from repro.launch import mesh as mesh_lib
+
+    mesh = mesh_lib.make_mesh((4,), ("data",))
+    dp = 4
+    params = {"w": jnp.zeros((8, 4))}
+    grads_t = {"w": jax.random.normal(jax.random.PRNGKey(0), (dp, 8, 4))}
+    method = ef.EF21SGDM(compressor=C.BlockTopK(block=4, k_per_block=2),
+                         eta=0.3)
+    gspecs = {"w": P("data", None, None)}
+
+    def scopes(op):
+        return [t for t in re.split(r"[/()]", op) if t in T.SCOPES]
+
+    for carrier, down in (("dense", None), ("fused", None),
+                          ("fused_quant8", None), ("quant8", None),
+                          ("sparse", "quant8")):
+        kw = {} if down is None else dict(
+            down_carrier=down, down_compressor=C.BlockTopK(block=4,
+                                                           k_per_block=2))
+        efc = D.EFConfig(method=method, carrier=carrier, data_axes=("data",),
+                         **kw)
+        st = D.init_ef_state(efc, params, dp, init_grads=grads_t)
+        sspecs = {"clients": {k: {"w": P("data", None, None)}
+                              for k in st["clients"]},
+                  "server": {"w": P(None, None)}}
+        if down is not None:
+            sspecs["h"] = {"w": P(None, None)}
+        with jax.set_mesh(mesh):
+            hlo = jax.jit(lambda g, s: D.ef_round_sharded(
+                efc, g, s, None, mesh, gspecs, sspecs)).lower(
+                grads_t, st).compile().as_text()
+        ops = [(l, m.group(1)) for l in hlo.splitlines()
+               for m in [re.search(r'op_name="([^"]*)"', l)] if m]
+        seen = {s for _, op in ops for s in scopes(op)}
+        want = {T.EF_CLIENT, T.EF_AGGREGATE}
+        if down is not None:
+            want.add(T.DOWNLINK)
+        assert want <= seen, (carrier, seen)
+        colls = [(l, op) for l, op in ops
+                 if re.search(r" (all-reduce|all-gather)(-start)?\\(", l)]
+        assert colls, carrier
+        for l, op in colls:
+            assert scopes(op) in ([T.EF_AGGREGATE], [T.DOWNLINK]), l
+        print(f"scopes {carrier} OK")
+    print("SCOPES_OK")
+""")
+
+
+def test_shardmap_round_carries_the_layer_scopes():
+    """The shard_map round names its client leg, its aggregation (where
+    the collective runs) and its downlink as the vmap round does."""
+    env = dict(os.environ, PYTHONPATH=SRC)
+    out = subprocess.run([sys.executable, "-c", SCOPES_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert "SCOPES_OK" in out.stdout, out.stdout + out.stderr
