@@ -32,7 +32,7 @@ def _ef_kernel(grad_ref, v_ref, g_ref, v_out, g_out, c_out, *,
     delta = v_new - g
     ab = jnp.abs(delta)
     t = _bisect_threshold(ab, k)
-    c = jnp.where(ab >= t[:, None], delta, 0.0)
+    c = jnp.where(ab >= t, delta, 0.0)
     v_out[...] = v_new.astype(v_out.dtype)
     g_out[...] = (g + c).astype(g_out.dtype)
     c_out[...] = c.astype(c_out.dtype)

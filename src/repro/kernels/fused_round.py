@@ -44,15 +44,18 @@ covering the EF21 broadcast-memory integration h' = h + decode(wire)
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import telemetry
 from repro.kernels.quantize import (_pad_rows, _row_tiles, pack_nibbles,
                                     unpack_nibbles)
 from repro.kernels.topk_compress import _bisect_threshold
+
+UPLINK = "ef21_sgdm_uplink"
 
 
 def _fused_uplink_kernel(grad_ref, v_ref, g_ref, v_out, g_out, q_out, s_out,
@@ -64,20 +67,20 @@ def _fused_uplink_kernel(grad_ref, v_ref, g_ref, v_out, g_out, q_out, s_out,
     v_new = v + eta * (grad - v)          # core/ef.py::tree_lerp's form
     delta = v_new - g
     ab = jnp.abs(delta)
-    t = _bisect_threshold(ab, k)
-    c = jnp.where(ab >= t[:, None], delta, 0.0)
+    t = _bisect_threshold(ab, k)          # (rt, 1)
+    c = jnp.where(ab >= t, delta, 0.0)
     # wire codec — same arithmetic as quantize._quant_kernel, one row per
     # selection block (the masked row's absmax is the selected values' absmax)
     c = jnp.where(jnp.isfinite(c), c, 0.0)
     qmax = float(2 ** (bits - 1) - 1)
-    scale = jnp.max(jnp.abs(c), axis=1) / qmax
+    scale = jnp.max(jnp.abs(c), axis=1, keepdims=True) / qmax
     safe = jnp.where(scale > 0, scale, 1.0)
-    q = jnp.clip(jnp.round(c / safe[:, None]), -qmax, qmax)
+    q = jnp.clip(jnp.round(c / safe), -qmax, qmax)
     # line 8 under the EF invariant: g' integrates what the server decodes
-    c_hat = q * scale[:, None]
+    c_hat = q * scale
     v_out[...] = v_new.astype(v_out.dtype)
     g_out[...] = (g + c_hat).astype(g_out.dtype)
-    s_out[...] = scale[:, None]
+    s_out[...] = scale
     if bits == 8:
         q_out[...] = q.astype(jnp.int8)
     else:
@@ -86,7 +89,8 @@ def _fused_uplink_kernel(grad_ref, v_ref, g_ref, v_out, g_out, q_out, s_out,
 
 def ef21_sgdm_topk_quant(grad: jax.Array, v: jax.Array, g: jax.Array, *,
                          eta: float, block: int = 1024, k: int = 16,
-                         bits: int = 8, rows_per_tile: int = 8,
+                         bits: int = 8,
+                         rows_per_tile: Optional[int] = None,
                          interpret: bool = False
                          ) -> Tuple[jax.Array, jax.Array, jax.Array,
                                     jax.Array]:
@@ -94,6 +98,16 @@ def ef21_sgdm_topk_quant(grad: jax.Array, v: jax.Array, g: jax.Array, *,
     (q, scales) is the quantized wire of c at the selection geometry — q int8
     (nb, block) for bits=8, packed uint4 pairs (nb, block//2) for bits=4 —
     and g' = g + dequantize(q, scales) (the EF invariant, enforced in-kernel).
+
+    The row tile is as tall as the per-operand VMEM budget allows
+    (``quantize._row_tiles``: 128 rows at block 1024), ``rows_per_tile``
+    an upper bound under it. The 26 bisection iterations are serially
+    dependent and a tile's rows are the only independent work in each, so
+    a short tile leaves every iteration waiting on its own cross-lane
+    count (8 rows ran each grid step at 13x its HBM time on a v5e). Rows
+    are independent, so the tile changes no output bit. Each launch's
+    rows, tile and grid steps are recorded at trace time
+    (``repro.telemetry.tiles``).
     """
     assert bits in (8, 4), bits
     assert bits == 8 or block % 2 == 0, "uint4 packing needs an even block"
@@ -101,6 +115,7 @@ def ef21_sgdm_topk_quant(grad: jax.Array, v: jax.Array, g: jax.Array, *,
     nb = -(-d // block)
     rt, nbp = _row_tiles(nb, block, rows_per_tile)
     pad = nbp * block - d
+    telemetry.record_tile(UPLINK, rows=nbp, block=block, rt=rt)
 
     def prep(x):
         return jnp.pad(x.reshape(-1), (0, pad)).reshape(nbp, block)
@@ -120,7 +135,7 @@ def ef21_sgdm_topk_quant(grad: jax.Array, v: jax.Array, g: jax.Array, *,
                    jax.ShapeDtypeStruct((nbp, qcols), qdtype),
                    jax.ShapeDtypeStruct((nbp, 1), jnp.float32)),
         interpret=interpret,
-        name="ef21_sgdm_uplink",
+        name=UPLINK,
     )(prep(grad), prep(v), prep(g))
 
     def unprep(x):

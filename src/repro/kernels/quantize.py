@@ -16,7 +16,7 @@ with a finite scale; an all-zero block gets scale 0 and decodes to exact zeros.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -89,27 +89,31 @@ def unpack_nibbles(p: jax.Array) -> jax.Array:
 _VMEM_TILE_WORDS = 1 << 17
 
 
-# Mosaic tiles the row (sublane) axis of a block in whole 8-row tiles.
-_SUBLANES = 8
+# Mosaic tiles a block's rows in whole 8-row sublane tiles, and VMEM holds
+# its last axis in whole 128-lane vregs.
+_SUBLANES, _LANES = 8, 128
 
 
-def _row_tiles(nb: int, block: int, rows_per_tile: int = 8
+def _row_tiles(nb: int, block: int, rows_per_tile: Optional[int] = 8
                ) -> Tuple[int, int]:
     """``(rt, nbp)``: rows per grid step, and the row count padded up to a
     whole number of steps.
 
-    ``rows_per_tile`` is an upper bound, further capped so one operand tile
-    (rt × block f32) stays within the per-operand VMEM budget — a lane-rounded
-    single-block leaf can make ``block`` itself huge. Mosaic takes a block
-    whose row count is a multiple of 8 or the whole array: an array of at
-    most one tile runs as one step, a longer one steps in multiples of 8
-    rows, with zero rows appended when no such tile divides it. Every kernel
-    here is row-independent, so the padding rows never touch a real row;
-    the callers slice them off. Lane alignment is the 128-wide last axis,
-    which the callers own — this helper only sizes the row axis.
+    ``rows_per_tile`` is an upper bound (None: none), further capped so one
+    operand tile (rt × block f32, the block rounded up to whole 128-lane
+    vregs as VMEM holds it) stays within the per-operand VMEM budget — a
+    lane-rounded single-block leaf can make ``block`` itself huge, and a
+    narrow block still fills whole lanes. Mosaic takes a block whose row
+    count is a multiple of 8 or the whole array: an array of at most one
+    tile runs as one step, a longer one steps in multiples of 8 rows, with
+    zero rows appended when no such tile divides it. Every kernel here is
+    row-independent, so the padding rows never touch a real row; the
+    callers slice them off. Lane alignment is the 128-wide last axis, which
+    the callers own — this helper only sizes the row axis.
     """
-    cap = max(1, _VMEM_TILE_WORDS // max(1, block))
-    rt = max(1, min(rows_per_tile, cap))
+    lanes = -(-max(1, block) // _LANES) * _LANES
+    cap = max(1, _VMEM_TILE_WORDS // lanes)
+    rt = cap if rows_per_tile is None else max(1, min(rows_per_tile, cap))
     if nb <= rt:
         return nb, nb
     if rt >= _SUBLANES:

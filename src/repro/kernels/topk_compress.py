@@ -24,15 +24,20 @@ BISECT_ITERS = 26
 
 
 def _bisect_threshold(ab: jax.Array, k: int) -> jax.Array:
-    """ab: (rows, block) |values|. Returns per-row threshold t s.t.
-    count(ab >= t) >= k and t is (approximately) maximal."""
-    hi = jnp.max(ab, axis=1)                      # count(>=hi) >= 1
+    """ab: (rows, block) |values|. Returns the (rows, 1) per-row threshold t
+    s.t. count(ab >= t) >= k and t is (approximately) maximal.
+
+    The per-row state stays a (rows, 1) column from the max to the last
+    count, so no iteration moves it between a lane-reduced column and a 1-D
+    vector and back: each iteration is compare, count along the lanes and
+    two selects, and the rows of a tile are its only independent work."""
+    hi = jnp.max(ab, axis=1, keepdims=True)       # count(>=hi) >= 1
     lo = jnp.zeros_like(hi)                       # count(>=0)  = block >= k
 
     def body(_, lohi):
         lo, hi = lohi
         mid = 0.5 * (lo + hi)
-        cnt = jnp.sum((ab >= mid[:, None]).astype(jnp.int32), axis=1)
+        cnt = jnp.sum((ab >= mid).astype(jnp.int32), axis=1, keepdims=True)
         ok = cnt >= k                             # mid keeps enough → raise lo
         lo = jnp.where(ok, mid, lo)
         hi = jnp.where(ok, hi, mid)
@@ -46,7 +51,7 @@ def _topk_kernel(x_ref, o_ref, *, k: int):
     x = x_ref[...].astype(jnp.float32)            # (rows, block)
     ab = jnp.abs(x)
     t = _bisect_threshold(ab, k)
-    o_ref[...] = jnp.where(ab >= t[:, None], x, 0.0).astype(o_ref.dtype)
+    o_ref[...] = jnp.where(ab >= t, x, 0.0).astype(o_ref.dtype)
 
 
 def block_topk(x: jax.Array, *, block: int = 1024, k: int = 16,

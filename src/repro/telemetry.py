@@ -29,6 +29,10 @@ compiling, and the persistent cache's hits and misses. Compiles outside a
 phase (another program's, a reference's) are not counted. A backend
 compile in phase ``step`` after a session's first step is a recompile, and
 is kept with its step number. ``counters()`` returns a plain snapshot.
+
+Kernel tiles. A Pallas kernel whose row tile follows its shape records,
+each time it is traced, its launch's rows, block, row tile and grid steps
+(``record_tile``); ``tiles()`` returns them in trace order.
 """
 from __future__ import annotations
 
@@ -187,3 +191,24 @@ def counters() -> Dict:
 def recompiles() -> List[dict]:
     """Each recompile so far: its step, seconds and function name."""
     return COUNTER.recompiles()
+
+
+_TILES: List[dict] = []
+_tiles_lock = threading.Lock()
+
+
+def record_tile(kernel: str, *, rows: int, block: int, rt: int) -> None:
+    """Record one traced launch of ``kernel``: ``rows`` × ``block`` as
+    launched (padding rows included), ``rt`` rows per grid step."""
+    with _tiles_lock:
+        _TILES.append({"kernel": kernel, "rows": int(rows),
+                       "block": int(block), "rt": int(rt),
+                       "steps": -(-int(rows) // int(rt))})
+
+
+def tiles(kernel: Optional[str] = None) -> List[dict]:
+    """Every launch recorded so far (of ``kernel`` alone, if given):
+    {"kernel", "rows", "block", "rt", "steps"}, in trace order."""
+    with _tiles_lock:
+        return [dict(t) for t in _TILES
+                if kernel is None or t["kernel"] == kernel]

@@ -172,3 +172,62 @@ def test_fused_carrier_round_matches_quant8_round():
         np.testing.assert_allclose(
             np.asarray(st_q["clients"][key]["w"]),
             np.asarray(st_f["clients"][key]["w"]), rtol=1e-6, atol=1e-7)
+
+
+def _padded_clients(x, clients, d, block):
+    """``fused_wire_round``'s batched layout: each client's leaf padded to
+    whole blocks, the clients stacked, so they become consecutive rows."""
+    pad = -(-d // block) * block - d
+    return jnp.pad(x.reshape(clients, d), ((0, 0), (0, pad)))
+
+
+# (shape of each operand, block, k, clients): several 128-row grid steps;
+# a row count that no multiple-of-8 tile divides, so padding rows run; two
+# clients' partial-block leaves stacked along the rows
+WIDE_TILE_CASES = {
+    "steps": ((3 * 128 * 1024,), 1024, 51, 1),
+    "pad_rows": ((389 * 1024 - 100,), 1024, 51, 1),
+    "clients": ((2, 192 * 1024 - 5), 1024, 51, 2),
+}
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+@pytest.mark.parametrize("case", sorted(WIDE_TILE_CASES))
+def test_fused_uplink_wide_tile_is_bit_identical(case, bits):
+    """The uplink at its own (VMEM-sized) row tile gives the same bits as
+    at 8 rows a tile: rows are independent and the bisection runs the
+    same iterations on each."""
+    from repro import telemetry
+
+    shape, block, k, clients = WIDE_TILE_CASES[case]
+    rng = np.random.RandomState(len(case) + bits)
+    grad, v, g = [jnp.asarray(rng.randn(*shape).astype(np.float32))
+                  for _ in range(3)]
+    if clients > 1:
+        d = shape[1]
+        grad, v, g = [_padded_clients(x, clients, d, block)
+                      for x in (grad, v, g)]
+    seen = len(telemetry.tiles(fr.UPLINK))
+    wide = fr.ef21_sgdm_topk_quant(grad, v, g, eta=0.1, block=block, k=k,
+                                   bits=bits, interpret=True)
+    rt, steps = (telemetry.tiles(fr.UPLINK)[seen][key]
+                 for key in ("rt", "steps"))
+    assert rt > 8 and steps > 1, (rt, steps)
+    eight = fr.ef21_sgdm_topk_quant(grad, v, g, eta=0.1, block=block, k=k,
+                                    bits=bits, rows_per_tile=8,
+                                    interpret=True)
+    assert telemetry.tiles(fr.UPLINK)[-1]["rt"] == 8
+    for a, b in zip(wide, eight):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("rows,rt", [
+    (240000, 128), (76800, 128), (28800, 128), (7200, 120), (8, 8),
+])
+def test_uplink_row_tile_on_the_cell_leaves(rows, rt):
+    """At block 1024 the VMEM budget allows 128 rows; a row count that 128
+    does not divide takes the largest multiple of 8 that does (no pad
+    copy), and a leaf of at most one tile runs as one step."""
+    from repro.kernels.quantize import _row_tiles
+    assert _row_tiles(rows, 1024, None) == (rt, rows)

@@ -218,6 +218,8 @@ def test_bisection_threshold_exactness():
     ab = jnp.abs(jnp.asarray(rng.randn(4, 1024).astype(np.float32)))
     for k in (1, 16, 300, 1024):
         t = np.asarray(_bisect_threshold(ab, k))
+        assert t.shape == (4, 1)
+        t = t[:, 0]
         kth = np.sort(np.asarray(ab), axis=1)[:, -k]
         cnt = (np.asarray(ab) >= t[:, None]).sum(1)
         assert (cnt >= k).all()

@@ -142,3 +142,24 @@ def test_counter_by_hand():
     with pytest.raises(ValueError):
         with c.phase("serve"):
             pass
+
+
+@pytest.mark.parametrize("rows_per_tile,rt,rows", [(None, 128, 384),
+                                                   (8, 8, 304)])
+def test_uplink_launch_records_its_tile(rows_per_tile, rt, rows):
+    """Tracing one uplink launch records its rows as launched, its row tile
+    and its grid steps: 300 rows at block 1024 pad to whole tiles."""
+    import functools
+
+    from repro.kernels import fused_round
+
+    seen = len(telemetry.tiles())
+    x = jax.ShapeDtypeStruct((300 * 1024,), jnp.float32)
+    jax.eval_shape(functools.partial(
+        fused_round.ef21_sgdm_topk_quant, eta=0.1, block=1024, k=51,
+        rows_per_tile=rows_per_tile, interpret=True), x, x, x)
+    assert telemetry.tiles()[seen:] == [
+        {"kernel": fused_round.UPLINK, "rows": rows, "block": 1024,
+         "rt": rt, "steps": rows // rt}]
+    assert telemetry.tiles(fused_round.UPLINK)[-1]["rt"] == rt
+    assert telemetry.tiles("no_such_kernel") == []

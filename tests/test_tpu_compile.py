@@ -9,6 +9,7 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and under pytest-xdist
 every worker imports this file."""
 import functools
+import importlib.util
 import os
 
 import jax
@@ -91,3 +92,35 @@ def test_kernel_compiles_for_v5e(name, leaf, one_chip):
     compiled = jax.jit(fn).lower(*specs).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         f"{name} at {LEAVES[leaf]} compiled without its Mosaic kernel"
+
+
+# the uplink's rows at h2o-danube3-4b's widths, 2 clients stacked as the
+# vmap round launches it: the embedding, an MLP projection and wk/wv
+CELL_ROWS = {"embed": 240_000, "mlp": 76_800, "wk": 7_200}
+ROOFLINE_READER = os.path.join(os.path.dirname(__file__), os.pardir, "bench",
+                               "metrics", "kernel.uplink.roofline.py")
+
+
+def _roofline_reader():
+    spec = importlib.util.spec_from_file_location("uplink_roofline",
+                                                  ROOFLINE_READER)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("leaf", sorted(CELL_ROWS))
+@pytest.mark.parametrize("bits", [8, 4])
+def test_uplink_compiles_at_the_cell_rows(bits, leaf, one_chip):
+    """The uplink at its VMEM-sized row tile compiles for a v5e at the
+    one-chip cell's leaf rows, as one Mosaic launch whose four outputs
+    (v', g', wire, scales) are what the roofline reader looks for."""
+    rows = CELL_ROWS[leaf]
+    fn = functools.partial(fused_round.ef21_sgdm_topk_quant, eta=ETA,
+                           block=BLOCK, k=K, bits=bits)
+    spec = jax.ShapeDtypeStruct((rows, BLOCK), jnp.float32,
+                                sharding=one_chip)
+    hlo = jax.jit(fn).lower(spec, spec, spec).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert len(_roofline_reader().launches(hlo)) == 1, \
+        f"the uplink at {rows} rows lost its four-output signature"
