@@ -25,7 +25,7 @@ Everything runs in this one process through the user-facing entry points
   trainer's bit for bit. ``ProcessFleet`` must refuse to start a worker on
   the chip this process holds.
 * four chips — the shard_map EF round with one client on each of four
-  chips (mesh ``pod``: data=4), against the vmap round of the same four
+  chips (mesh ``host4``: data=4), against the vmap round of the same four
   clients on one chip, on ``dense`` and ``fused_quant8``: each of 3 steps
   feeds both rounds the same state and per-client gradients, and their
   server estimates must agree within the carrier tests' tolerance.
@@ -66,11 +66,10 @@ SERVE = dict(TRAIN, carrier="dense", downlink_carrier="quant8",
              ef_state_dtype="bfloat16")
 SERVE_STEPS = 2
 REQUESTS, PROMPT, NEW_TOKENS = 4, 128, 16
-# four chips: mesh 'pod' shrinks to (data=4, model=1) on a 2x2 host, one EF
-# client per chip in ef_round_sharded: the data-parallel layout users run.
-# The spec checks the global batch against mesh 'pod''s 16 clients at full
-# size, so it is 16: four sequences per client.
-FOUR = dict(TRAIN, global_batch=16, mesh="pod")
+# four chips: mesh 'host4' is (data=4, model=1) on a 2x2 host, one EF client
+# per chip in ef_round_sharded: the data-parallel layout users run, with
+# four sequences per client.
+FOUR = dict(TRAIN, global_batch=16, mesh="host4")
 FOUR_CARRIERS = ("dense", "fused_quant8")
 # the vmap round's reference runs on one chip piece by piece, since four
 # clients' state and gradients do not fit one chip whole: a piece is at

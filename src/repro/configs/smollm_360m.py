@@ -1,9 +1,9 @@
-"""smollm-360m — small llama-arch dense LM [hf:HuggingFaceTB/SmolLM-135M]."""
+"""smollm-360m — small llama-arch dense LM [hf:HuggingFaceTB/SmolLM-360M]."""
 import dataclasses
 from repro.configs.base import ArchConfig
 
 CONFIG = ArchConfig(
-    name="smollm-360m", family="dense", citation="hf:HuggingFaceTB/SmolLM-135M",
+    name="smollm-360m", family="dense", citation="hf:HuggingFaceTB/SmolLM-360M",
     num_layers=32, d_model=960, num_heads=15, num_kv_heads=5, d_ff=2560,
     vocab_size=49152,
 )

@@ -95,8 +95,9 @@ from typing import Any, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.core import compressors as comp_lib
-from repro.telemetry import EF_AGGREGATE, EF_CLIENT
+from repro.telemetry import EF_AGGREGATE, EF_CLIENT, EF_COLLECTIVE
 
 PyTree = Any
 Wire = Any
@@ -105,6 +106,34 @@ Wire = Any
 # (the broadcast must be one identical message on server and all clients, so
 # its key is derived from the round rng BEFORE any per-client folding)
 DOWNLINK_FOLD = 1 << 20
+
+
+def pmean(x: jax.Array, axes) -> jax.Array:
+    """``lax.pmean`` of one leaf over the client axes, as it computes it:
+    the all-reduce (a ``psum``, under ``EF_COLLECTIVE`` with its operand
+    recorded by ``telemetry.record_collective``), then the division by the
+    number of clients outside the scope."""
+    telemetry.record_collective("all-reduce", axes, x.size, x.dtype)
+    with jax.named_scope(EF_COLLECTIVE):
+        total = jax.lax.psum(x, axes)
+    n = 1
+    for a in (axes,) if isinstance(axes, str) else axes:
+        n *= jax.lax.axis_size(a)
+    return total / n
+
+
+def all_gather(x: jax.Array, axis_name) -> jax.Array:
+    """``lax.all_gather`` of one leaf over a client axis, under
+    ``EF_COLLECTIVE`` with its operand recorded."""
+    telemetry.record_collective("all-gather", axis_name, x.size, x.dtype)
+    with jax.named_scope(EF_COLLECTIVE):
+        return jax.lax.all_gather(x, axis_name)
+
+
+def _ppermute(x: jax.Array, axis_name, perm) -> jax.Array:
+    telemetry.record_collective("ppermute", axis_name, x.size, x.dtype)
+    with jax.named_scope(EF_COLLECTIVE):
+        return jax.lax.ppermute(x, axis_name, perm)
 
 
 def ring_all_gather(x: PyTree, axis_name, fn=None) -> PyTree:
@@ -131,7 +160,7 @@ def ring_all_gather(x: PyTree, axis_name, fn=None) -> PyTree:
         # chunk s arrives from ring-neighbor s hops back while fn(chunk s−1)
         # is still runnable — the double buffer is (buf, chunks[-1])
         buf = jax.tree_util.tree_map(
-            lambda a: jax.lax.ppermute(a, axis_name, perm), buf)
+            lambda a: _ppermute(a, axis_name, perm), buf)
         chunks.append(fn(buf))
     stacked = jax.tree_util.tree_map(lambda *cs: jnp.stack(cs), *chunks)
     # chunks[s] came from axis index (me − s) mod n; re-index to axis order
@@ -210,7 +239,7 @@ class Carrier:
         if self.overlap:
             return ring_all_gather(x, axis_name, fn)
         gathered = jax.tree_util.tree_map(
-            lambda a: jax.lax.all_gather(a, axis_name), x)
+            lambda a: all_gather(a, axis_name), x)
         if fn is None:
             return gathered
         return jax.vmap(fn)(gathered)
@@ -328,7 +357,7 @@ class DenseCarrier(Carrier):
 
     def aggregate(self, comp, wire, *, d, dtype, dp=None, axes=None):
         if axes is not None:
-            return jax.lax.pmean(wire, axes)
+            return pmean(wire, axes)
         return wire.mean(0)
 
     def wire_words(self, comp, d):
@@ -717,7 +746,7 @@ class QuantCarrier(Carrier):
             return buf.reshape(-1)[:d].astype(dtype)
         if axes is not None:                             # dense payload:
             deq = self.decode(comp, wire, d=d, dtype=jnp.float32)
-            return jax.lax.pmean(deq, axes).astype(dtype)  # dequant THEN psum
+            return pmean(deq, axes).astype(dtype)  # dequant THEN psum
         q, scales = wire                                 # (dp, nbq, ·) layout
         dp_, nbq = scales.shape
         vals = kref.block_dequantize_ref(
@@ -890,7 +919,7 @@ class FusedQuantCarrier(QuantCarrier):
                                      axes=axes)
         if axes is not None:                             # dense-payload rule:
             deq = self.decode(comp, wire, d=d, dtype=jnp.float32)
-            return jax.lax.pmean(deq, axes).astype(dtype)  # dequant THEN psum
+            return pmean(deq, axes).astype(dtype)  # dequant THEN psum
         q, scales = wire                                 # (dp, nb, ·) layout
         _, block, _ = self._fused_geom(comp, d)
         dp_, nb = scales.shape
@@ -983,7 +1012,7 @@ class FusedQuantCarrier(QuantCarrier):
                     vals = kref.block_dequantize_ref(
                         q, scales, bits=self.bits, cols=block)
                     dec = vals.reshape(-1)[:d].astype(jnp.float32)
-                    msg = (jax.lax.pmean(dec, axes)  # dense-payload rule:
+                    msg = (pmean(dec, axes)  # dense-payload rule:
                            .reshape(grad.shape)      # dequant THEN psum
                            .astype(grad.dtype))
             v_out.append(v2)

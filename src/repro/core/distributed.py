@@ -57,7 +57,7 @@ from repro.core import hierarchy as hier_lib
 from repro.core import participation as part_lib
 from repro.core import schedule as sched_lib
 from repro.telemetry import (DOWNLINK, EF_AGGREGATE, EF_CLIENT, MODEL,
-                             OPTIMIZER)
+                             OPTIMIZER, collective_round)
 
 PyTree = Any
 
@@ -227,7 +227,11 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
 
     This keeps compression 100% collective-free (no flatten-induced gathers) and
     makes the collective schedule ours rather than the SPMD partitioner's.
+    Every collective of the aggregation goes through ``carriers.pmean`` /
+    ``carriers.all_gather``: scoped ``ef_collective`` and recorded, one
+    ``telemetry.collective_round`` per trace of this round.
     """
+    collective_round()
     method = efc.method
     c_axes = efc.data_axes
     sched = efc.schedule
@@ -293,7 +297,7 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
         # EF_AGGREGATE for the decode and the collective
         pmean = jax.named_scope(EF_AGGREGATE)(
             lambda t: jax.tree_util.tree_map(
-                lambda m: jax.lax.pmean(m, intra_axes), t))
+                lambda m: carrier_lib.pmean(m, intra_axes), t))
         if sched is not None:
             # grouped engine: one wire (and one aggregation collective) per
             # group, each on its group's carrier/compressor
@@ -391,7 +395,7 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
         b_new = hier_lib.cross_sync(hops, sched, t_new, st["b"], r_pod)
         pod_msg = hier_lib.pod_message(method, st["b"], b_new)
         server_msg = jax.tree_util.tree_map(
-            lambda m: jax.lax.pmean(m, ("pod",)), pod_msg)
+            lambda m: carrier_lib.pmean(m, ("pod",)), pod_msg)
         return server_msg, ex({"t": t_new, "b": b_new})
 
     if efc.has_downlink:

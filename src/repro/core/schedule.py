@@ -458,7 +458,8 @@ def round_local(schedule: CompressionSchedule, method, grads: PyTree,
     freeze/rescale postlude stays at the CALLER. Returns
     ``(msg_mean, new_states)``."""
     pmean = jax.named_scope(EF_AGGREGATE)(
-        lambda t: jax.tree_util.tree_map(lambda m: jax.lax.pmean(m, axes), t))
+        lambda t: jax.tree_util.tree_map(
+            lambda m: carrier_lib.pmean(m, axes), t))
 
     def leg(m_g, carrier, plan, grads_g, states_g, r_g):
         if plan == "fused":

@@ -22,6 +22,8 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
 PROD_DATA = 16
 PROD_MODEL = 16
 PROD_PODS = 2
+# one four-chip host (a v5e 2x2): one EF client per chip, no model axis
+HOST4 = (4, 1)
 
 
 def _shrink_shape(shape: Tuple[int, ...], n_devices: int) -> Tuple[int, ...]:
@@ -58,6 +60,20 @@ def make_production_mesh(*, multi_pod: bool = False):
     if n_dev < need:
         shape = _shrink_shape(shape, n_dev)
     return make_mesh(shape, axes)
+
+
+def make_host4_mesh():
+    """(data=4, model=1) on the first four devices: one four-chip host, one
+    EF client per chip. Fewer devices are refused, not shrunk: the spec's
+    client count (and its batch check) is the mesh's."""
+    devs = jax.devices()
+    need = HOST4[0] * HOST4[1]
+    if len(devs) < need:
+        raise ValueError(f"mesh 'host4' needs {need} devices, JAX found "
+                         f"{len(devs)}")
+    return jax.make_mesh(HOST4, ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2,
+                         devices=devs[:need])
 
 
 def client_axes(mesh) -> Tuple[str, ...]:

@@ -304,6 +304,8 @@ class Session:
     def _make_mesh(name: str):
         if name == "smoke":
             return mesh_lib.make_smoke_mesh()
+        if name == "host4":
+            return mesh_lib.make_host4_mesh()
         return mesh_lib.make_production_mesh(multi_pod=(name == "multi_pod"))
 
     def mesh_context(self):
@@ -387,8 +389,10 @@ class Session:
 
         rng = jax.random.PRNGKey(spec.seed)
 
-        def init_state(b0):
-            params = model_lib.init_params(cfg, rng)
+        # the key is an argument, not a constant of the program: every seed
+        # runs the one compiled init
+        def init_state(b0, key):
+            params = model_lib.init_params(cfg, key)
             # Alg 1 line 2: v⁰ᵢ = g⁰ᵢ = (1/B_init)Σⱼ ∇fᵢ(x⁰, ξ⁰ᵢⱼ)
             _, _, g0 = dist.per_client_value_and_grad(loss_fn, params, b0, n)
             ef_state = dist.init_ef_state(efc, params, n, init_grads=g0)
@@ -408,21 +412,24 @@ class Session:
             # state, and the first compile of the step serves every step
             named = lambda s: sh.NamedSharding(mesh, s)  # noqa: E731
             b_specs = sh.batch_pspecs(cfg, mesh, "train", spec.global_batch)
-            init_fn = jax.jit(init_state, in_shardings=(
-                {k: named(s) for k, s in b_specs.items()},), out_shardings={
-                "params": named(sh.P()), "opt_state": named(sh.P()),
-                "ef_state": jax.tree_util.tree_map(
-                    named, state_specs,
-                    is_leaf=lambda x: isinstance(x, sh.P))})
+            init_fn = jax.jit(
+                init_state,
+                in_shardings=({k: named(s) for k, s in b_specs.items()},
+                              named(sh.P())),
+                out_shardings={
+                    "params": named(sh.P()), "opt_state": named(sh.P()),
+                    "ef_state": jax.tree_util.tree_map(
+                        named, state_specs,
+                        is_leaf=lambda x: isinstance(x, sh.P))})
         else:
             init_fn = jax.jit(init_state)
         b0 = pipe_lib.with_prefix_embeds(cfg, pipe.batch(0))
         with self._ambient():
             if template:
-                state = jax.eval_shape(init_state, b0)
+                state = jax.eval_shape(init_state, b0, rng)
             else:
                 with telemetry.span("init"), telemetry.phase("init"):
-                    state = init_fn(b0)
+                    state = init_fn(b0, rng)
         self._tr = {
             "efc": efc, "opt": opt, "pipe": pipe, "loss_fn": loss_fn,
             "step_fn": step_fn, "rng": rng, "grads_specs": grads_specs,

@@ -105,12 +105,13 @@ CARRIERS = frozenset({"dense", "sparse", "fused", "quant8", "quant4",
 DOWN_CARRIERS = frozenset(CARRIERS - {"fused"})
 OPTIMIZERS = frozenset({"sgd", "adamw"})
 
-MESHES = ("smoke", "pod", "multi_pod")
-# geometry mirror of launch/mesh.py (PROD_DATA/PROD_MODEL/PROD_PODS)
+MESHES = ("smoke", "pod", "multi_pod", "host4")
+# geometry mirror of launch/mesh.py (PROD_DATA/PROD_MODEL/PROD_PODS, HOST4)
 MESH_GEOM: Dict[str, Dict[str, int]] = {
     "smoke": {"data": 1, "model": 1},
     "pod": {"data": 16, "model": 16},
     "multi_pod": {"pod": 2, "data": 16, "model": 16},
+    "host4": {"data": 4, "model": 1},
 }
 
 GRANULARITIES = ("group", "pod")
@@ -537,7 +538,7 @@ class RunSpec:
     global_batch: int = 16
 
     # -- mesh / placement (ShardPlan) ----------------------------------------
-    mesh: str = "smoke"                    # 'smoke' | 'pod' | 'multi_pod'
+    mesh: str = "smoke"                    # one of MESHES
     client_granularity: str = "group"      # ShardPlan: 'group' | 'pod'
     state_sharding: str = "client"         # ShardPlan: 'client' | 'zero'
     ef_state_dtype: Optional[str] = None   # ShardPlan: None | 'bfloat16'
@@ -877,9 +878,10 @@ class RunSpec:
             errs.append(f"hops: pods={pods} must divide the {n} EF clients "
                         f"of mesh={self.mesh!r} "
                         f"granularity={self.client_granularity!r}")
-        if self.mesh == "pod":
-            errs.append("hops: mesh='pod' has no pod axis — hierarchical "
-                        "aggregation (pods > 1) needs mesh='multi_pod' or "
+        if self.mesh in ("pod", "host4"):
+            errs.append(f"hops: mesh={self.mesh!r} has no pod axis — "
+                        "hierarchical aggregation (pods > 1) needs "
+                        "mesh='multi_pod' or "
                         "the single-device smoke mesh (vmap emulation)")
         if self.mesh == "multi_pod" \
                 and pods != MESH_GEOM["multi_pod"]["pod"]:

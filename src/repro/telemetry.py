@@ -12,7 +12,11 @@ metadata of every HLO instruction lowered under it, as a path component
 that a transform may wrap (``vmap(transpose(jvp(model_fwd_bwd)))``), so
 the ops of a profiler trace can be put down to a layer; it costs nothing
 at run time. Each Pallas kernel carries its own ``name=`` in the same
-path.
+path. The cross-chip collectives of ``ef_round_sharded``'s aggregation
+(the clients' all-reduces and all-gathers, the intra- and cross-pod
+means) run under ``EF_COLLECTIVE``, nested inside ``EF_AGGREGATE``; it is
+not one of ``SCOPES``, so a split of the step by layer still counts them
+in the aggregation.
 
 Host spans. ``span(name)`` is a ``jax.profiler.TraceAnnotation`` named
 ``repro.<name>`` and ``step_span(n)`` the ``repro.step`` step annotation;
@@ -30,6 +34,12 @@ phase (another program's, a reference's) are not counted. A backend
 compile in phase ``step`` after a session's first step is a recompile, and
 is kept with its step number. ``counters()`` returns a plain snapshot.
 
+Collectives. Each such collective records, each time it is traced, its
+operation, mesh axes and operand size on one chip
+(``record_collective``); ``ef_round_sharded`` opens a new round of records
+each time it is traced (``collective_round``), so ``collectives()`` gives
+one step's collectives as the records of one round.
+
 Kernel tiles. A Pallas kernel whose row tile follows its shape records,
 each time it is traced, its launch's rows, block, row tile and grid steps
 (``record_tile``); ``tiles()`` returns them in trace order.
@@ -38,9 +48,10 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import jax
+import numpy as np
 
 MODEL = "model_fwd_bwd"
 EF_CLIENT = "ef_client"
@@ -48,6 +59,7 @@ EF_AGGREGATE = "ef_aggregate"
 DOWNLINK = "downlink"
 OPTIMIZER = "optimizer"
 SCOPES = (MODEL, EF_CLIENT, EF_AGGREGATE, DOWNLINK, OPTIMIZER)
+EF_COLLECTIVE = "ef_collective"
 
 SPAN_PREFIX = "repro."
 PHASES = ("init", "step")
@@ -212,3 +224,40 @@ def tiles(kernel: Optional[str] = None) -> List[dict]:
     with _tiles_lock:
         return [dict(t) for t in _TILES
                 if kernel is None or t["kernel"] == kernel]
+
+
+_COLLECTIVES: List[dict] = []
+_rounds = 0
+_collectives_lock = threading.Lock()
+
+
+def collective_round() -> int:
+    """Open the next round of recorded collectives and return its number:
+    the records that follow, until the next call, are one trace of one EF
+    round."""
+    global _rounds
+    with _collectives_lock:
+        _rounds += 1
+        return _rounds
+
+
+def record_collective(op: str, axes: Sequence[str], elements: int,
+                      dtype) -> None:
+    """Record one traced collective ``op`` over mesh ``axes`` whose operand
+    holds ``elements`` of ``dtype`` on each chip."""
+    if isinstance(axes, str):
+        axes = (axes,)
+    with _collectives_lock:
+        _COLLECTIVES.append({
+            "op": op, "axes": tuple(axes), "elements": int(elements),
+            "bytes": int(elements) * np.dtype(dtype).itemsize,
+            "round": _rounds})
+
+
+def collectives(last_round: bool = False) -> List[dict]:
+    """Every collective recorded so far (of the latest round alone, if
+    ``last_round``): {"op", "axes", "elements", "bytes", "round"}, in trace
+    order."""
+    with _collectives_lock:
+        return [dict(c) for c in _COLLECTIVES
+                if not last_round or c["round"] == _rounds]

@@ -191,3 +191,84 @@ def test_shardmap_round_carries_the_layer_scopes():
     out = subprocess.run([sys.executable, "-c", SCOPES_SCRIPT], env=env,
                          capture_output=True, text=True, timeout=600)
     assert "SCOPES_OK" in out.stdout, out.stdout + out.stderr
+
+
+COLLECTIVE_SCRIPT = textwrap.dedent("""
+    import os, re
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import jax, jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from repro import telemetry as T
+    from repro.core import compressors as C, distributed as D, ef
+    from repro.launch import mesh as mesh_lib
+
+    mesh = mesh_lib.make_host4_mesh()
+    assert dict(mesh.shape) == {"data": 4, "model": 1}
+    assert list(mesh.devices.flat) == jax.devices()[:4]
+    dp = 4
+    params = {"b": jnp.zeros((6,)), "w": jnp.zeros((8, 4))}
+    key = jax.random.PRNGKey(0)
+    grads_t = {k: jax.random.normal(jax.random.fold_in(key, i),
+                                    (dp,) + v.shape)
+               for i, (k, v) in enumerate(sorted(params.items()))}
+    method = ef.EF21SGDM(compressor=C.BlockTopK(block=4, k_per_block=2),
+                         eta=0.3)
+    gspecs = {"b": P("data", None), "w": P("data", None, None)}
+
+    for carrier in ("dense", "fused_quant8", "quant8"):
+        efc = D.EFConfig(method=method, carrier=carrier, data_axes=("data",))
+        st = D.init_ef_state(efc, params, dp, init_grads=grads_t)
+        sspecs = {"clients": {k: gspecs for k in st["clients"]},
+                  "server": {"b": P(None), "w": P(None, None)}}
+        with jax.set_mesh(mesh):
+            hlo = jax.jit(lambda g, s: D.ef_round_sharded(
+                efc, g, s, None, mesh, gspecs, sspecs)).lower(
+                grads_t, st).compile().as_text()
+        colls = [(l, re.search(r'op_name="([^"]*)"', l)) for l in
+                 hlo.splitlines()
+                 if re.search(r" (all-reduce|all-gather)(-start)?\\(", l)]
+        assert colls, carrier
+        for l, m in colls:
+            path = re.split(r"[/()]", m.group(1)) if m else []
+            assert T.EF_COLLECTIVE in path, l
+            assert T.EF_COLLECTIVE not in T.SCOPES
+            assert (path.index(T.EF_AGGREGATE)
+                    < path.index(T.EF_COLLECTIVE)), l
+        rows = T.collectives(last_round=True)
+        ops = {"dense": {"all-reduce"}, "fused_quant8": {"all-reduce"},
+               "quant8": {"all-gather"}}[carrier]
+        assert rows and {r["op"] for r in rows} == ops, (carrier, rows)
+        assert all(r["axes"] == ("data",) for r in rows), rows
+        print(f"collective scope {carrier} OK")
+
+    # fused_quant8: one f32 all-reduce per leaf, its local elements' bytes,
+    # recorded once in each trace of the round
+    efc = D.EFConfig(method=method, carrier="fused_quant8",
+                     data_axes=("data",))
+    st = D.init_ef_state(efc, params, dp, init_grads=grads_t)
+    sspecs = {"clients": {k: gspecs for k in st["clients"]},
+              "server": {"b": P(None), "w": P(None, None)}}
+    before = len(T.collectives())
+    for _ in range(2):
+        with jax.set_mesh(mesh):
+            jax.jit(lambda g, s: D.ef_round_sharded(
+                efc, g, s, None, mesh, gspecs, sspecs)).lower(grads_t, st)
+    rows = T.collectives()[before:]
+    assert len({r["round"] for r in rows}) == 2, rows
+    last = T.collectives(last_round=True)
+    assert [r["bytes"] for r in last] == [4 * 6, 4 * 32], last
+    assert [r["elements"] for r in last] == [6, 32], last
+    assert len(rows) == 2 * len(last), rows
+    print("COLLECTIVES_OK")
+""")
+
+
+def test_sharded_round_scopes_and_counts_its_collectives():
+    """Every all-reduce and all-gather of the sharded EF aggregation runs
+    under ``ef_collective`` inside ``ef_aggregate``, on mesh host4 (the
+    first four devices), and the collective counter records each leaf's
+    f32 operand once per trace of the round."""
+    env = dict(os.environ, PYTHONPATH=SRC)
+    out = subprocess.run([sys.executable, "-c", COLLECTIVE_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert "COLLECTIVES_OK" in out.stdout, out.stdout + out.stderr

@@ -41,6 +41,32 @@ def test_mesh_geometry_matches_mesh_module():
     assert spec_lib.MESH_GEOM["multi_pod"] == {
         "pod": mesh_lib.PROD_PODS, "data": mesh_lib.PROD_DATA,
         "model": mesh_lib.PROD_MODEL}
+    assert spec_lib.MESH_GEOM["host4"] == dict(zip(("data", "model"),
+                                                   mesh_lib.HOST4))
+
+
+def test_host4_is_four_clients_and_refuses_fewer_devices(monkeypatch):
+    """Mesh host4 is one four-chip host, one client per chip: the spec
+    checks its batch against 4 clients, and the mesh is built from exactly
+    four devices, never shrunk onto fewer."""
+    import jax
+
+    from repro.launch import mesh as mesh_lib
+    from repro.launch.session import Session
+
+    assert mesh_lib.HOST4 == (4, 1)
+    spec = RunSpec(mesh="host4", global_batch=4, seq_len=32)
+    assert spec.n_clients_preview() == 4
+    with pytest.raises(ValueError, match="not divisible by the 4 EF"):
+        RunSpec(mesh="host4", global_batch=6)
+    with pytest.raises(ValueError, match="mesh='host4' has no pod axis"):
+        RunSpec(mesh="host4", global_batch=4, hops={"pods": 2})
+    two = jax.devices()[:1] * 2
+    monkeypatch.setattr(jax, "devices", lambda *a: two)
+    with pytest.raises(ValueError, match="needs 4 devices, JAX found 2"):
+        mesh_lib.make_host4_mesh()
+    with pytest.raises(ValueError, match="needs 4 devices, JAX found 2"):
+        Session(spec)
 
 
 def test_attribute_mirrors_match_method_and_compressor_classes():
