@@ -125,10 +125,26 @@ class EFConfig:
 # per-client gradients
 # ---------------------------------------------------------------------------
 
+def _client_split(dp: int) -> Optional[Tuple[str, ...]]:
+    """The ambient mesh's client axes (``pod``, ``data``) when they split
+    ``dp`` clients evenly, else None: the axes that carry the clients'
+    dimension through their forward and backward, so that a kernel run
+    under ``shard_map`` there gets its own clients' rows."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty:
+        return None
+    axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    n = 1
+    for a in axes:
+        n *= mesh.shape[a]
+    return axes if axes and dp % n == 0 else None
+
+
 def per_client_value_and_grad(loss_fn: Callable, params: PyTree, batch: PyTree,
                               dp: int) -> Tuple[jax.Array, PyTree, PyTree]:
     """loss_fn(params, sub_batch) -> (loss, aux). Returns (mean loss, aux,
-    per-client grads with a leading dp axis)."""
+    per-client grads with a leading dp axis). Under a mesh, the clients'
+    dimension is split over its client axes (:func:`_client_split`)."""
     def reshape(leaf):
         b = leaf.shape[0]
         assert b % dp == 0, f"global batch {b} not divisible by dp={dp}"
@@ -140,7 +156,8 @@ def per_client_value_and_grad(loss_fn: Callable, params: PyTree, batch: PyTree,
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, b)
         return loss, aux, grads
 
-    losses, auxs, grads = jax.vmap(one)(batch_g)
+    losses, auxs, grads = jax.vmap(one, spmd_axis_name=_client_split(dp))(
+        batch_g)
     aux = jax.tree_util.tree_map(lambda a: a.mean(0), auxs)
     return losses.mean(), aux, grads
 

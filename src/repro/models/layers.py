@@ -1,12 +1,18 @@
 """Shared transformer building blocks: RMSNorm, RoPE, GQA attention (full /
 sliding-window banded / decode-with-cache), SwiGLU MLP.
 
-Attention is implemented as *chunked* attention: an outer ``lax.scan`` over query
-chunks keeps the HLO small and the live score tensor bounded at
-``(B, H, chunk_q, S_kv)`` — the pure-JAX analogue of the Pallas flash kernel
-(kernels/flash_attention.py), which is used on real TPU. Sliding-window layers use a
-*banded* schedule: each query chunk attends only to a ``window + chunk`` KV slice →
-O(S·W) FLOPs instead of O(S²).
+Attention takes one of three paths, chosen by ``attn_apply`` from the call's
+shape alone. Full causal self-attention with no softcap, no binding window,
+``H % KV == 0`` and a sequence that the kernel's block divides (training and
+cache-less forwards at S = 256, 512, ...) runs as one fused Pallas flash
+kernel, forward and backward (``kernels/ops.py::causal_gqa_attention``):
+compiled by Mosaic on a TPU, interpreted on the CPU. Every other
+full-sequence call (prefill with a cache, a binding sliding window, a
+softcap, short sequences) runs *chunked* attention: an outer ``lax.scan``
+over query chunks keeps the HLO small and the live score tensor bounded at
+``(B, H, chunk_q, S_kv)``; sliding-window layers use a *banded* schedule
+(each query chunk attends only to a ``window + chunk`` KV slice → O(S·W)
+FLOPs instead of O(S²)). Decoding one token attends to its cache in jnp.
 """
 from __future__ import annotations
 
@@ -15,6 +21,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from repro import telemetry
+from repro.kernels import ops as kernel_ops
 
 Array = jax.Array
 
@@ -55,14 +64,18 @@ def softcap(x: Array, cap: Optional[float]) -> Array:
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope(x: Array, positions: Array, theta: float = 10000.0) -> Array:
-    """x: (..., S, H, hd); positions: (..., S) absolute positions."""
+def rope(x: Array, positions: Array, theta: float = 10000.0,
+         scale: Optional[float] = None) -> Array:
+    """x: (..., S, H, hd); positions: (..., S) absolute positions. ``scale``
+    multiplies the rotated x in f32, before its one cast back to x's dtype."""
     hd = x.shape[-1]
     freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
     ang = positions[..., None].astype(jnp.float32) * freqs        # (..., S, hd/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    if scale is not None:
+        out = out * scale
     return out.astype(x.dtype)
 
 
@@ -213,11 +226,42 @@ def attn_apply(p: dict, x: Array, positions: Array, *, rope_theta: float,
     k = jnp.einsum("bsd,dnh->bsnh", h, p["wk"].astype(h.dtype))
     v = jnp.einsum("bsd,dnh->bsnh", h, p["wv"].astype(h.dtype))
     q = shard(q, None, None, "model", None)
-    q = rope(q, positions, rope_theta)
+    B, S, H, hd = q.shape
+    KV = k.shape[2]
+    # the fused kernel: full causal self-attention (no cache, so q and k are
+    # the same positions) that no softcap or window changes, whole blocks
+    block = None
+    if cache is None and cap is None and (window is None or window >= S) \
+            and H % KV == 0:
+        block = kernel_ops.causal_attention_block(S)
+    # the kernel takes q already scaled: fold 1/sqrt(hd) in before q's one
+    # rounding to the compute dtype
+    q = rope(q, positions, rope_theta,
+             scale=None if block is None else hd ** -0.5)
     k = rope(k, positions, rope_theta)
+    with jax.named_scope(telemetry.ATTENTION):
+        out, new_cache = _attend(q, k, v, block, chunk=chunk, window=window,
+                                 cap=cap, cache=cache, pos_scalar=pos_scalar)
+    out = shard(out, None, None, "model", None)
+    return jnp.einsum("bsnh,nhd->bsd", out, p["wo"].astype(out.dtype)), new_cache
+
+
+def _attend(q: Array, k: Array, v: Array, block: Optional[int], *,
+            chunk: int, window: Optional[int], cap: Optional[float],
+            cache: Optional[Tuple[Array, Array]], pos_scalar: Optional[Array],
+            ) -> Tuple[Array, Optional[Tuple[Array, Array]]]:
+    """``attn_apply``'s attention core on roped q, k, v: the fused kernel
+    where ``block`` is set, else decode against the cache or chunked
+    attention (filling the cache on prefill). Records the path taken."""
+    B, Sq, H, hd = q.shape
+    shape = dict(B=B, S=Sq, H=H, KV=k.shape[2], hd=hd)
+    if block is not None:
+        telemetry.record_attention("kernel", block=block, **shape)
+        return kernel_ops.causal_gqa_attention(q, k, v), None
 
     new_cache = None
-    if cache is not None and x.shape[1] == 1:
+    if cache is not None and Sq == 1:
+        telemetry.record_attention("decode", **shape)
         kc, vc = cache
         S = kc.shape[1]
         slot = pos_scalar if window is None else pos_scalar % S
@@ -226,14 +270,15 @@ def attn_apply(p: dict, x: Array, positions: Array, *, rope_theta: float,
         out = decode_attention(q, kc, vc, pos_scalar, window=window, cap=cap)
         new_cache = (kc, vc)
     else:
+        telemetry.record_attention("chunked", **shape)
         out = chunked_attention(q, k, v, chunk=chunk, window=window, cap=cap)
         if cache is not None:     # prefill: write the (possibly windowed) tail
             kc, vc = cache
             S = kc.shape[1]
-            if window is not None and x.shape[1] > S:
+            if window is not None and Sq > S:
                 # keep last W keys; ring-buffer alignment: slot p mod S
                 tail_k, tail_v = k[:, -S:], v[:, -S:]
-                roll = (x.shape[1] % S)
+                roll = (Sq % S)
                 kc = jnp.roll(tail_k.astype(kc.dtype), roll, axis=1)
                 vc = jnp.roll(tail_v.astype(vc.dtype), roll, axis=1)
             else:
@@ -242,9 +287,7 @@ def attn_apply(p: dict, x: Array, positions: Array, *, rope_theta: float,
                 vc = jax.lax.dynamic_update_slice_in_dim(
                     vc, v[:, :S].astype(vc.dtype), 0, axis=1)
             new_cache = (kc, vc)
-
-    out = shard(out, None, None, "model", None)
-    return jnp.einsum("bsnh,nhd->bsd", out, p["wo"].astype(out.dtype)), new_cache
+    return out, new_cache
 
 
 # ---------------------------------------------------------------------------

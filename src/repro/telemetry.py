@@ -16,7 +16,9 @@ path. The cross-chip collectives of ``ef_round_sharded``'s aggregation
 (the clients' all-reduces and all-gathers, the intra- and cross-pod
 means) run under ``EF_COLLECTIVE``, nested inside ``EF_AGGREGATE``; it is
 not one of ``SCOPES``, so a split of the step by layer still counts them
-in the aggregation.
+in the aggregation. Attention, on either of its paths, runs under
+``ATTENTION``, nested inside ``MODEL`` in the same way: ``MODEL``'s time
+still counts it.
 
 Host spans. ``span(name)`` is a ``jax.profiler.TraceAnnotation`` named
 ``repro.<name>`` and ``step_span(n)`` the ``repro.step`` step annotation;
@@ -43,6 +45,12 @@ one step's collectives as the records of one round.
 Kernel tiles. A Pallas kernel whose row tile follows its shape records,
 each time it is traced, its launch's rows, block, row tile and grid steps
 (``record_tile``); ``tiles()`` returns them in trace order.
+
+Attention calls. Each attention call records, each time it is traced, the
+path it took (``"kernel"``: the fused flash kernel; ``"chunked"``: the jnp
+query-chunk scan; ``"decode"``: one token against its cache), its shape and
+the kernel's block (``record_attention``); ``attention_calls()`` returns
+them in trace order.
 """
 from __future__ import annotations
 
@@ -60,6 +68,7 @@ DOWNLINK = "downlink"
 OPTIMIZER = "optimizer"
 SCOPES = (MODEL, EF_CLIENT, EF_AGGREGATE, DOWNLINK, OPTIMIZER)
 EF_COLLECTIVE = "ef_collective"
+ATTENTION = "attention"
 
 SPAN_PREFIX = "repro."
 PHASES = ("init", "step")
@@ -261,3 +270,34 @@ def collectives(last_round: bool = False) -> List[dict]:
     with _collectives_lock:
         return [dict(c) for c in _COLLECTIVES
                 if not last_round or c["round"] == _rounds]
+
+
+_ATTENTION: List[dict] = []
+_attention_lock = threading.Lock()
+
+
+def record_attention(path: str, *, B: int, S: int, H: int, KV: int,
+                     hd: int, block: Optional[int] = None
+                     ) -> None:
+    """Record one traced attention call that took ``path`` (``"kernel"``,
+    ``"chunked"`` or ``"decode"``) on ``B`` sequences of ``S`` tokens, ``H``
+    query and ``KV`` key/value heads of ``hd``; ``block`` is the kernel's
+    query and key block, in its forward, dK/dV and dQ passes."""
+    with _attention_lock:
+        _ATTENTION.append({"path": path, "B": int(B), "S": int(S),
+                           "H": int(H), "KV": int(KV), "hd": int(hd),
+                           "block": None if block is None
+                           else int(block)})
+
+
+def attention_calls() -> List[dict]:
+    """Every attention call recorded so far: {"path", "B", "S", "H", "KV",
+    "hd", "block"}, in trace order."""
+    with _attention_lock:
+        return [dict(a) for a in _ATTENTION]
+
+
+def clear_attention_calls() -> None:
+    """Forget the attention calls recorded so far."""
+    with _attention_lock:
+        _ATTENTION.clear()

@@ -14,9 +14,10 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from repro.kernels import ef_update, fused_round, quantize, topk_compress
+from repro.kernels import ef_update, fused_round, ops, quantize, topk_compress
 
 # smollm-360m leaves: one MLP projection (d_model × d_ff) and the embedding
 LEAVES = {"mlp": (2560, 960), "embed": (49152, 960)}
@@ -124,3 +125,66 @@ def test_uplink_compiles_at_the_cell_rows(bits, leaf, one_chip):
     assert "tpu_custom_call" in hlo
     assert len(_roofline_reader().launches(hlo)) == 1, \
         f"the uplink at {rows} rows lost its four-output signature"
+
+
+# each cell's attention call as one chip traces it: (B, S, H, KV, hd)
+ATTENTION_SHAPES = {"smollm-360m": (4, 1024, 15, 5, 64),
+                    "h2o-danube3-4b": (8, 2048, 32, 8, 120)}
+
+
+@pytest.mark.parametrize("cell", sorted(ATTENTION_SHAPES))
+def test_causal_gqa_attention_compiles_for_v5e(cell, one_chip, monkeypatch):
+    """The fused causal GQA kernel's forward and its dQ and dK/dV backward
+    compile for a v5e at each cell's attention shape, head dim 120
+    included, as Mosaic launches."""
+    B, S, H, KV, hd = ATTENTION_SHAPES[cell]
+    # the process's backend is the CPU's: build the kernel as the chip would
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+
+    def loss(q, k, v):
+        return jnp.sum(ops.causal_gqa_attention(q, k, v).astype(jnp.float32))
+
+    specs = [jax.ShapeDtypeStruct((B, S, n, hd), jnp.bfloat16,
+                                  sharding=one_chip) for n in (H, KV, KV)]
+    hlo = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        *specs).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 3, \
+        f"{cell}: forward, dQ and dK/dV did not all compile as Mosaic kernels"
+
+
+def test_causal_gqa_attention_compiles_across_four_chips(topo, monkeypatch):
+    """The four-chip cell's attention layer, forward and backward, compiles
+    for a v5e 2x2 (mesh ``host4``: data 4, model 1) inside the clients'
+    vmap as the train step runs it: batch split over ``data``, weights
+    replicated. Mosaic cannot partition a kernel itself, so this holds only
+    if each chip runs the kernel on its own shard."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core import distributed as dist
+    from repro.models import layers as L
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    B, S, H, KV, hd = ATTENTION_SHAPES["smollm-360m"]
+    d, clients = 960, 4
+    mesh = jax.sharding.Mesh(
+        np.array(topo.devices).reshape(4, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    params = jax.eval_shape(lambda: L.attn_init(
+        jax.random.PRNGKey(0), d, H, KV, hd, jnp.bfloat16))
+    params = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(mesh, P())), params)
+    x = jax.ShapeDtypeStruct((clients * B, S, d), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("data")))
+
+    def loss(p, x):
+        pos = jnp.broadcast_to(jnp.arange(S), x.shape[:2])
+        out, _ = L.attn_apply(p, x, pos, rope_theta=1e4, eps=1e-6, chunk=512)
+        return jnp.mean(out.astype(jnp.float32) ** 2), ()
+
+    def step(p, x):
+        return dist.per_client_value_and_grad(loss, p, x, clients)[2]
+
+    with jax.set_mesh(mesh):
+        hlo = jax.jit(step).lower(params, x).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 3, \
+        "forward, dQ and dK/dV did not all compile as Mosaic kernels"
