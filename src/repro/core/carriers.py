@@ -4,9 +4,9 @@ EF21's separation result (Richtárik et al., 2021) is that the *wire format* of
 the compressed innovation is independent of the *method semantics* — Algorithm 1
 only requires that every client ships C(vᵢ − gᵢ) and receives meanᵢ(cᵢ). This
 module makes that separation first-class (DESIGN.md §6): a :class:`Carrier`
-owns how c travels, every runtime (the vmap simulator in core/simulate.py, the
-vmap runtime ``ef_round``, and the shard_map runtime ``ef_round_sharded`` in
-core/distributed.py) dispatches through it, and methods never see the wire.
+owns how c travels, both runtimes (the vmap ``ef_round``, which the simulator
+in core/simulate.py drives, and the shard_map ``ef_round_sharded``, in
+core/distributed.py) dispatch through it, and methods never see the wire.
 
 The carriers:
 
@@ -1092,7 +1092,7 @@ def downlink_round(carrier: Carrier, comp, delta: PyTree,
     plan the broadcast ships the dense C(delta) tensor itself.
 
     The pure-jnp ``encode`` runs on every runtime (never ``encode_local``):
-    the broadcast is one unbatched message, and keeping all three runtimes on
+    the broadcast is one unbatched message, and keeping both runtimes on
     one code path is what makes the round-trip state-sync tests bit-exact
     across them."""
     plan = carrier.plan_down(comp)

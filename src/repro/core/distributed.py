@@ -225,6 +225,56 @@ def _participation_mask(efc: EFConfig, n: int, step):
     return part_lib.cohort_mask(part, n, step)
 
 
+def check_composition(efc: EFConfig, eta, n: int, mask) -> None:
+    """The round's composition rule, checked once before its plan switch:
+    a sampled cohort (``mask``) cannot run the fused wire, and neither a
+    sampled cohort nor the fused wire can run under a hierarchy. The fused
+    wire is the plan the carrier (or any schedule group's) executes at
+    ``eta``. ``launch/build.py`` refuses the same configs by carrier name."""
+    if efc.schedule is None:
+        plans = {carrier_lib.make(efc.carrier).plan(efc.method, eta)}
+    else:
+        plans = {carrier_lib.make(g.carrier).plan(
+            sched_lib.group_method(efc.method, g), eta)
+            for g in efc.schedule.groups}
+    fused_wire = "fused_wire" in plans
+    if mask is not None and fused_wire:
+        raise ValueError(
+            "sampled participation cannot run the fused_wire plan: the "
+            "mega-kernel aggregates all clients inside, leaving no "
+            "per-client wire to mask")
+    hops = efc.effective_hops
+    if hops is None:
+        return
+    hier_lib.check_pods(hops, n)
+    if mask is not None:
+        raise ValueError(
+            "sampled participation does not compose with hierarchical "
+            "aggregation: a per-round cohort has no stable pod membership")
+    if fused_wire:
+        raise ValueError(
+            "fused_wire carriers aggregate all clients inside the "
+            "mega-kernel — there is no per-pod message to re-aggregate")
+
+
+def downlink_leg(efc: EFConfig, g_server: PyTree, h: PyTree,
+                 rng: Optional[jax.Array], memory: bool = True
+                 ) -> Tuple[PyTree, Optional[PyTree]]:
+    """The downlink broadcast of ``g_server`` against the memory ``h``:
+    per schedule group, or on the single downlink carrier. Its key comes
+    off the ROUND rng (never a per-client fold): the broadcast is one
+    identical message everywhere. ``memory=False`` is the naive broadcast
+    of C(g) (``ef.downlink_sync``). Returns ``(g_est, h_new)``."""
+    r_down = None if rng is None else jax.random.fold_in(rng, DOWNLINK_FOLD)
+    with jax.named_scope(DOWNLINK):
+        if efc.schedule is not None:
+            return sched_lib.downlink_round_grouped(
+                efc.schedule, g_server, h, r_down, memory=memory)
+        return ef_lib.downlink_sync(
+            carrier_lib.make(efc.down_carrier), efc.down_comp(), g_server,
+            h, rng=r_down, memory=memory)
+
+
 def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
                      rng: Optional[jax.Array], mesh, grads_specs: PyTree,
                      state_specs: Dict, eta: Optional[float] = None,
@@ -256,8 +306,6 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
     if efc.overlap:
         carrier = dataclasses.replace(carrier, overlap=True)
     plan = carrier.plan(method, eta)
-    down_carrier = carrier_lib.make(efc.down_carrier)
-    down_comp = efc.down_comp()
 
     n_total = 1
     for a in c_axes:
@@ -281,15 +329,7 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
             raise ValueError(
                 f"hops.pods={hops.pods} must equal the mesh pod axis "
                 f"({mesh.shape['pod']})")
-        if mask_full is not None:
-            raise ValueError(
-                "sampled participation does not compose with hierarchical "
-                "aggregation (guarded at spec/build construction)")
-        if plan == "fused_wire":
-            raise ValueError(
-                "fused_wire carriers aggregate all clients inside the "
-                "mega-kernel — there is no per-pod message to re-aggregate "
-                "(guarded at spec/build construction)")
+    check_composition(efc, eta, n_total, mask_full)
     # the collective axes of the intra hop: everything when flat or when the
     # cross hop is trivial (legacy bits), the non-pod axes otherwise
     intra_axes = c_axes if trivial_cross \
@@ -328,11 +368,6 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
                     c_tree = part_lib.apply_mask(mask_m, c_tree)
             msg_mean = pmean(c_tree)
         elif plan == "fused_wire":
-            if mask_m is not None:
-                # unreachable behind the spec/build construction errors: the
-                # mega-kernel aggregates inside, no per-client wire to mask
-                raise ValueError(
-                    "sampled participation cannot run the fused_wire plan")
             # one mega-kernel launch per leaf: update + select + quantize +
             # EF-invariant integration; the aggregated mean comes back with
             # the new client state (aggregation needs the wire)
@@ -418,10 +453,6 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
     if efc.has_downlink:
         def body(grads_l, clients_l, server_l, h_l, rng_l, *rest):
             pods_l, mask_l = split_rest(rest)
-            # the downlink key comes off the round rng BEFORE the per-client
-            # fold: the broadcast must be one identical message everywhere
-            r_down = None if rng_l is None \
-                else jax.random.fold_in(rng_l, DOWNLINK_FOLD)
             new_cl, msg_mean = client_leg(
                 grads_l, clients_l, fold_client(rng_l), mask_l)
             with jax.named_scope(EF_AGGREGATE):
@@ -433,14 +464,7 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
             # travels) and the same decode its client would run. Sampling
             # composes for free: h is server-side, so a client absent for k
             # rounds still integrated every broadcast and re-enters in sync
-            with jax.named_scope(DOWNLINK):
-                if sched is not None:
-                    g_est, h_new = sched_lib.downlink_round_grouped(
-                        sched, new_server, h_l, r_down)
-                else:
-                    g_est, h_new = ef_lib.downlink_sync(
-                        down_carrier, down_comp, new_server, h_l,
-                        rng=r_down)
+            g_est, h_new = downlink_leg(efc, new_server, h_l, rng_l)
             out = (new_cl, new_server, h_new, g_est)
             return out + ((new_pods,) if hops is not None else ())
 
@@ -489,11 +513,12 @@ def ef_round_sharded(efc: EFConfig, grads: PyTree, ef_state: Dict,
     return new_server, new_state
 
 
-def ef_round(efc: EFConfig, grads: PyTree, ef_state: Dict,
-             rng: Optional[jax.Array], eta: Optional[float] = None,
-             step: Optional[jax.Array] = None) -> Tuple[PyTree, Dict]:
-    """vmap EF sync (single-device tests, exact global-TopK semantics).
-    grads: per-client (dp leading). Returns (gᵗ⁺¹ estimate, new ef_state)."""
+def uplink_round(efc: EFConfig, grads: PyTree, ef_state: Dict,
+                 rng: Optional[jax.Array], eta: Optional[float] = None,
+                 step: Optional[jax.Array] = None) -> Tuple[PyTree, Dict]:
+    """The vmap round up to the server: the clients' update and wire, the
+    cohort, the pod tier and the server step. grads: per-client (dp
+    leading). Returns (new server estimate, new ef_state without 'h')."""
     method, dp = efc.method, jax.tree_util.tree_leaves(grads)[0].shape[0]
     clients, server = ef_state["clients"], ef_state["server"]
     carrier = carrier_lib.make(efc.carrier)
@@ -509,17 +534,7 @@ def ef_round(efc: EFConfig, grads: PyTree, ef_state: Dict,
     trivial_cross = hops is None or hier_lib.cross_is_trivial(
         hops, efc.schedule)
     want_pods = hops is not None and not trivial_cross
-    if hops is not None:
-        hier_lib.check_pods(hops, dp)
-        if mask is not None:
-            raise ValueError(
-                "sampled participation does not compose with hierarchical "
-                "aggregation (guarded at spec/build construction)")
-        if plan == "fused_wire":
-            raise ValueError(
-                "fused_wire carriers aggregate all clients inside the "
-                "mega-kernel — there is no per-pod message to re-aggregate "
-                "(guarded at spec/build construction)")
+    check_composition(efc, eta, dp, mask)
     agg = jax.named_scope(EF_AGGREGATE)(
         (lambda t: hier_lib.pod_mean(t, hops.pods)) if want_pods
         else (lambda t: jax.tree_util.tree_map(lambda m: m.mean(0), t)))
@@ -539,11 +554,6 @@ def ef_round(efc: EFConfig, grads: PyTree, ef_state: Dict,
                 c_tree = part_lib.apply_mask(mask, c_tree)
         msg_mean = agg(c_tree)
     elif plan == "fused_wire":
-        if mask is not None:
-            # unreachable behind the spec/build construction errors: the
-            # mega-kernel aggregates inside, no per-client wire to mask
-            raise ValueError(
-                "sampled participation cannot run the fused_wire plan")
         msg_mean, new_clients = carrier.fused_wire_round(
             method, grads, clients, eta=eta, batched=True, dp=dp)
     elif plan == "wire":
@@ -596,18 +606,20 @@ def ef_round(efc: EFConfig, grads: PyTree, ef_state: Dict,
             new_state["pods"] = new_pods if want_pods else \
                 hier_lib.trivial_bookkeeping(method, ef_state["pods"],
                                              msg_mean)
+    return new_server, new_state
+
+
+def ef_round(efc: EFConfig, grads: PyTree, ef_state: Dict,
+             rng: Optional[jax.Array], eta: Optional[float] = None,
+             step: Optional[jax.Array] = None) -> Tuple[PyTree, Dict]:
+    """vmap EF sync (single-device runs, exact global-TopK semantics): the
+    uplink round, then the downlink leg when there is one. grads:
+    per-client (dp leading). Returns (gᵗ⁺¹ estimate, new ef_state)."""
+    new_server, new_state = uplink_round(efc, grads, ef_state, rng, eta,
+                                         step)
     if not efc.has_downlink:
         return new_server, new_state
-    r_down = None if rng is None else jax.random.fold_in(rng, DOWNLINK_FOLD)
-    with jax.named_scope(DOWNLINK):
-        if efc.schedule is not None:
-            g_est, h_new = sched_lib.downlink_round_grouped(
-                efc.schedule, new_server, ef_state["h"], r_down)
-        else:
-            g_est, h_new = ef_lib.downlink_sync(
-                carrier_lib.make(efc.down_carrier), efc.down_comp(),
-                new_server, ef_state["h"], rng=r_down)
-    new_state["h"] = h_new
+    g_est, new_state["h"] = downlink_leg(efc, new_server, ef_state["h"], rng)
     return g_est, new_state
 
 

@@ -179,7 +179,7 @@ def trivial_bookkeeping(method, pods_st, msg_mean):
     """Pod-memory update under a TRIVIAL cross hop: the aggregator is
     transparent (b' = t'), the server consumed the legacy GLOBAL mean
     bit-exactly, and the pod memories track that same global innovation —
-    one rule shared by all three runtimes so they agree bit-for-bit.
+    one rule shared by both runtimes so they agree bit-for-bit.
     ``msg_mean`` broadcasts against the pod state, which carries a leading
     pods axis in the vmap runtimes and none inside shard_map."""
     def up(t_leaf, m_leaf):

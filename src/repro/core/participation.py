@@ -9,16 +9,17 @@ round. The source paper's EF21-SGDM momentum buffer is exactly the per-client
 state that must stay consistent across skipped rounds, which is why the
 freeze is a hard tree-level ``where`` and not a "small update".
 
-Both synchronous runtimes (core/simulate.py, core/distributed.py) implement
-the rule by MASKING: the sampled cohort is a seeded 0/1 mask over clients
-(:func:`cohort_mask` — a pure function of (seed, round), so resume replays
-identical cohorts), non-sampled wire contributions are zeroed BEFORE the
-aggregation collective (C(0) = 0 exactly for every deterministic wire
-compressor, so a zero-masked delta produces an exactly-zero decode), and the
-whole per-client state tree is frozen afterwards with :func:`freeze_tree`.
+Both synchronous runtimes (``ef_round``, which core/simulate.py drives, and
+``ef_round_sharded``, in core/distributed.py) implement the rule by MASKING:
+the sampled cohort is a seeded 0/1 mask over clients (:func:`cohort_mask` —
+a pure function of (seed, round), so resume replays identical cohorts),
+non-sampled wire contributions are zeroed BEFORE the aggregation collective
+(C(0) = 0 exactly for every deterministic wire compressor, so a zero-masked
+delta produces an exactly-zero decode), and the whole per-client state tree
+is frozen afterwards with :func:`freeze_tree`.
 A fraction-1.0 cohort multiplies by 1.0 and ``where(True, …)`` everywhere —
 IEEE-exact — so the masked path is BIT-identical to full participation
-(tests/test_participation.py pins this on all three runtimes).
+(tests/test_participation.py pins this on both runtimes and the simulator).
 
 Absolute-mode methods (EF14, SGDM, …) have no server increment to divide by
 n; their server state is the cohort mean (1/|S|)·Σ_{i∈S} msgᵢ, i.e. the

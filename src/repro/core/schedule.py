@@ -18,9 +18,9 @@ one group by construction. Patterns are ``|``-separated substring tokens
 matched against the leaf's ``/``-joined lower-cased key path (``"norm|bias"``
 matches ``layers/mlp/norm``; ``"*"`` matches everything).
 
-This module also hosts the *grouped execution engine* every runtime
-dispatches through (the vmap simulator in core/simulate.py, ``ef_round`` and
-``ef_round_sharded`` in core/distributed.py): per group, the existing
+This module also hosts the *grouped execution engine* both runtimes
+dispatch through (``ef_round``, which core/simulate.py drives, and
+``ef_round_sharded``, in core/distributed.py): per group, the existing
 single-compressor machinery runs unchanged on that group's leaf list — the
 same pre_compress → C(·) → post_compress chain, the same carrier plans
 ('dense' | 'wire' | 'fused' | 'fused_wire'), the same downlink broadcast
@@ -380,7 +380,8 @@ def round_batched(schedule: CompressionSchedule, method, grads: PyTree,
     groups). ``pods > 1`` (DESIGN.md §13) returns PER-POD means on a leading
     pods axis (pod-major client blocks) instead of the global mean — the
     intra hop of the hierarchical topology; the caller's pod tier owns the
-    cross hop. Returns ``(msg_mean, new_states)``."""
+    cross hop. The caller has checked the composition
+    (``distributed.check_composition``). Returns ``(msg_mean, new_states)``."""
     if pods > 1 and dp % pods:
         raise ValueError(f"pods={pods} must divide the client count {dp}")
 
@@ -404,15 +405,6 @@ def round_batched(schedule: CompressionSchedule, method, grads: PyTree,
                     c_tree = part_lib.apply_mask(mask, c_tree)
             return agg(c_tree), new_st
         if plan == "fused_wire":
-            if mask is not None:
-                # unreachable behind the spec/build construction errors
-                raise ValueError("sampled participation cannot run the "
-                                 "fused_wire plan")
-            if pods > 1:
-                # unreachable behind the spec/build construction errors
-                raise ValueError("the fused_wire plan cannot run under a "
-                                 "hierarchical topology (its wire IS the "
-                                 "global aggregation)")
             return carrier.fused_wire_round(
                 m_g, grads_g, states_g, eta=eta, batched=True, dp=dp)
         if plan == "wire":
@@ -470,10 +462,6 @@ def round_local(schedule: CompressionSchedule, method, grads: PyTree,
                     c_tree = part_lib.apply_mask(mask, c_tree)
             return pmean(c_tree), new_st
         if plan == "fused_wire":
-            if mask is not None:
-                # unreachable behind the spec/build construction errors
-                raise ValueError("sampled participation cannot run the "
-                                 "fused_wire plan")
             return carrier.fused_wire_round(
                 m_g, grads_g, states_g, eta=eta, axes=axes)
         if plan == "wire":

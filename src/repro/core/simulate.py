@@ -3,15 +3,14 @@
 This is the exact loop of Algorithm 1 / 3 / 4 / 5 (and the EF14/SGD baselines) run
 over an arbitrary :class:`repro.core.problems.Problem`, with all n clients carried as
 a leading axis and stepped by ``vmap`` — a faithful single-host emulation of the
-distributed method that the paper's own experiments use. The production multi-chip
-path lives in core/distributed.py; both share the Method implementations AND the
-wire carrier (core/carriers.py), so what is validated here is what runs on the
-mesh: ``SimConfig.carrier`` selects dense / sparse / fused / quant8 / quant4
-exactly like ``EFConfig.carrier`` does on the production path, and
-``SimConfig.down_carrier`` / ``down_compressor`` add the same downlink
-broadcast leg (EF21 server memory h, DESIGN.md §8) the production runtimes
-run — plus the simulator-only ``down_memory=False`` naive-broadcast ablation
-the paper-claims tests use.
+distributed method that the paper's own experiments use. It steps the vmap
+round of core/distributed.py: each scan step draws the clients' gradients,
+then runs ``uplink_round`` and ``downlink_leg``, so what is validated here is the
+round the production path runs. :class:`SimConfig` names the round's settings
+(carrier, downlink, schedule, participation, hops) as ``EFConfig`` does
+(:meth:`SimConfig.ef_config`); on top of the round the simulator owns the step
+sizes (constant or time-varying γₜ, ηₜ), the ``down_memory=False``
+naive-broadcast ablation the paper-claims tests use, and the wire accounting.
 """
 from __future__ import annotations
 
@@ -23,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import carriers as carrier_lib
-from repro.core import compressors as comp_lib
+from repro.core import distributed as dist
 from repro.core import ef as ef_lib
 from repro.core import hierarchy as hier_lib
 from repro.core import participation as part_lib
@@ -45,43 +44,33 @@ class SimConfig:
     carrier: str = "dense"     # any core/carriers.py REGISTRY name:
     # 'dense'|'sparse'|'fused'|'quant8'|'quant4'|'fused_quant8'|'fused_quant4'
     # downlink (server → client broadcast) leg, DESIGN.md §8. The default
-    # ('dense', no compressor) is the unidirectional simulator, bit-identical
-    # to pre-downlink behavior. ``down_memory=False`` is the NAIVE ablation
-    # (broadcast C(g) with no server memory — nothing re-sends the
-    # compression error; the paper-claims tests show it stalling).
+    # ('dense', no compressor) runs no downlink. ``down_memory=False`` is the
+    # NAIVE ablation (broadcast C(g) with no server memory — nothing re-sends
+    # the compression error; the paper-claims tests show it stalling).
     down_carrier: str = "dense"
     down_compressor: Optional[Any] = None   # a Compressor (frozen → hashable)
     down_memory: bool = True
     # per-parameter-group compression (DESIGN.md §9): when set, the round and
     # the wire accounting dispatch through the grouped engine in
-    # core/schedule.py, exactly like EFConfig.schedule on the production
-    # runtimes; the single-knob carrier/down_* fields above are ignored.
+    # core/schedule.py; the single-knob carrier/down_* fields are ignored.
     schedule: Optional[sched_lib.CompressionSchedule] = None
     # partial participation (DESIGN.md §11): mode='sampled' masks each round
-    # to a seeded cohort — non-sampled clients' wires are zeroed before the
-    # aggregation and their ENTIRE EF state (gᵢ, momentum) stays bit-frozen
-    # (the "EF21 with Bells & Whistles" rule). None / mode='full' is the
-    # legacy full-cohort loop; fraction=1.0 sampling is bit-identical to it.
+    # to a seeded cohort (the "EF21 with Bells & Whistles" rule).
     # mode='async' never runs here — core/participation.py::run_async is the
     # event-driven simulator.
     participation: Optional[part_lib.Participation] = None
     # two-tier hierarchical aggregation (DESIGN.md §13): clients → pod
-    # aggregator → global server, with the cross-pod hop on its own
-    # carrier/compressor and its own EF memory per pod. None or pods=1 is
-    # the flat loop, bit-identical to today. Mirrors EFConfig.hops exactly
-    # (same Hops knob, same trivial-cross flat-equivalence regime).
+    # aggregator → global server, the cross-pod hop on its own carrier and
+    # EF memory per pod. None or pods=1 is the flat loop.
     hops: Optional[hier_lib.Hops] = None
 
-    @property
-    def effective_hops(self) -> Optional[hier_lib.Hops]:
-        return hier_lib.effective(self.hops)
-
-    @property
-    def has_downlink(self) -> bool:
-        if self.schedule is not None:
-            return self.schedule.has_downlink
-        return (self.down_carrier != "dense"
-                or self.down_compressor is not None)
+    def ef_config(self, method: ef_lib.Method) -> dist.EFConfig:
+        """The EFConfig of the round this simulation runs."""
+        return dist.EFConfig(
+            method=method, carrier=self.carrier,
+            down_carrier=self.down_carrier,
+            down_compressor=self.down_compressor, schedule=self.schedule,
+            participation=self.participation, hops=self.hops)
 
 
 def _client_rngs(rng, n):
@@ -109,60 +98,16 @@ def run(problem, method: ef_lib.Method, cfg: SimConfig, rng: jax.Array) -> Dict:
         return jax.tree_util.tree_map(lambda g: g.mean(0), gs)
 
     g0 = jax.vmap(init_grad_one)(clients, _client_rngs(r_init, cfg.n))
-    if cfg.schedule is not None:
-        states = jax.vmap(lambda g: sched_lib.init_state_grouped(
-            cfg.schedule, method, x0, init_grads=g))(g0)
-    else:
-        states = jax.vmap(lambda g: method.init(x0, init_grads=g))(g0)
-    g_server = ef_lib.server_init(
-        method, x0, jax.tree_util.tree_map(lambda g: g.mean(0), g0))
-
-    carrier = carrier_lib.make(cfg.carrier)
-    has_down = cfg.has_downlink
-    down_car = carrier_lib.make(cfg.down_carrier)
-    down_comp = cfg.down_compressor if cfg.down_compressor is not None \
-        else comp_lib.Identity()
-    part = cfg.participation
-    if part is not None and part.mode == "async":
-        raise ValueError(
-            "participation mode 'async' does not run on the synchronous "
-            "simulator (every scan step is a barrier); drive the "
-            "event-driven simulator instead: "
-            "repro.core.participation.run_async")
-    sampling = part is not None and part.is_sampling
-    m_cohort = part.cohort_size(cfg.n) if sampling else cfg.n
-
-    # two-tier hierarchy (DESIGN.md §13): mirrors ef_round exactly — a
-    # non-trivial cross hop pod-means the intra aggregation (pod-major
-    # client blocks) and runs the per-pod cross sync; a trivial cross keeps
-    # the legacy global aggregation ops verbatim (flat-equivalence anchor)
-    hops = cfg.effective_hops
-    trivial_cross = hops is None or hier_lib.cross_is_trivial(
-        hops, cfg.schedule)
-    want_pods = hops is not None and not trivial_cross
-    if hops is not None:
-        hier_lib.check_pods(hops, cfg.n)
-        if sampling:
-            raise ValueError(
-                "sampled participation does not compose with hierarchical "
-                "aggregation (guarded at spec/build construction)")
-
-    def agg_mean(tree):
-        if want_pods:
-            return hier_lib.pod_mean(tree, hops.pods)
-        return jax.tree_util.tree_map(lambda m: m.mean(0), tree)
+    efc = cfg.ef_config(method)
+    has_down = efc.has_downlink
+    eta0 = cfg.eta if cfg.eta is not None else getattr(method, "eta", 1.0)
 
     def step(carry, t):
-        pods_st = carry[-1] if hops is not None else None
-        if has_down:
-            # g_est is what the clients reconstructed last round — the
-            # broadcast memory h under EF21-BC, or the latest naive decode
-            x, states, g_server, g_est, rng = carry[:5]
-        else:
-            x, states, g_server, rng = carry[:4]
-            g_est = g_server        # implicit dense broadcast
+        x, ef_state, rng = carry
+        # g_est is what the clients reconstructed last round: the broadcast
+        # memory h (or the latest naive decode), else the server estimate
+        g_est = ef_state["h"] if has_down else ef_state["server"]
         rng, r_grad, r_comp = jax.random.split(rng, 3)
-        eta0 = cfg.eta if cfg.eta is not None else getattr(method, "eta", 1.0)
         if cfg.time_varying:
             # App. J schedule: γₜ = γ/√(t+1), ηₜ = 1/√(t+1)
             scale = 1.0 / jnp.sqrt(t + 1.0)
@@ -186,111 +131,29 @@ def run(problem, method: ef_lib.Method, cfg: SimConfig, rng: jax.Array) -> Dict:
                 return (g_new, problem.stoch_grad(x, c, rg, cfg.batch_size))
             return problem.stoch_grad(x_next, c, rg, cfg.batch_size)
 
-        r_grads = _client_rngs(r_grad, cfg.n)
-        # cohort mask for this round (DESIGN.md §11): seeded pure in
-        # (seed, t), so kill-and-resume replays the exact cohort sequence
-        mask = part_lib.cohort_mask(part, cfg.n, t) if sampling else None
-        plan = carrier.plan(method, eta_t)   # static: traced ηₜ forces 'dense'
-        if cfg.schedule is not None:
-            grads = jax.vmap(client_grads)(clients, r_grads)
-            msg_mean, states_new = sched_lib.round_batched(
-                cfg.schedule, method, grads, states, cfg.n, r_comp, eta_t,
-                mask=mask, pods=hops.pods if want_pods else 1)
-        elif plan == "fused":
-            grads = jax.vmap(client_grads)(clients, r_grads)
-            c_tree, states_new = carrier.fused_update(
-                method, grads, states, eta=eta_t, batched=True)
-            if mask is not None:
-                c_tree = part_lib.apply_mask(mask, c_tree)
-            msg_mean = agg_mean(c_tree)
-        elif plan == "fused_wire":
-            if mask is not None:
-                # unreachable behind the spec/build construction errors: the
-                # mega-kernel aggregates inside, no per-client wire to mask
-                raise ValueError(
-                    "sampled participation cannot run the fused_wire plan")
-            if hops is not None:
-                raise ValueError(
-                    "fused_wire carriers aggregate all clients inside the "
-                    "mega-kernel — there is no per-pod message to "
-                    "re-aggregate (guarded at spec/build construction)")
-            grads = jax.vmap(client_grads)(clients, r_grads)
-            msg_mean, states_new = carrier.fused_wire_round(
-                method, grads, states, eta=eta_t, batched=True, dp=cfg.n)
-        elif plan == "wire":
-            grads = jax.vmap(client_grads)(clients, r_grads)
-            deltas, ctxs = jax.vmap(
-                lambda g, s: method.pre_compress(g, s, eta=eta_t))(
-                grads, states)
-            if mask is not None:
-                # zero-masked wires: C(0) = 0 exactly, the carrier's own
-                # aggregation then folds only the sampled cohort
-                deltas = part_lib.apply_mask(mask, deltas)
-            c_tree, wire_mean = carrier_lib.wire_round_batched(
-                carrier, method.compressor, deltas, cfg.n)
-            # non-trivial hops pod-mean the per-client messages (local_c IS
-            # the decode of what traveled); the global aggregate is DCE'd
-            msg_mean = agg_mean(c_tree) if want_pods else wire_mean
-            _, states_new = jax.vmap(method.post_compress)(c_tree, ctxs)
-        else:
-            def client_update(c, st, rg, rc):
-                return method.update(client_grads(c, rg), st, rc, eta=eta_t)
-            msgs, states_new = jax.vmap(client_update)(
-                clients, states, r_grads, _client_rngs(r_comp, cfg.n))
-            if mask is not None:
-                msgs = part_lib.apply_mask(mask, msgs)
-            msg_mean = agg_mean(msgs)
-        if mask is not None:
-            # Bells & Whistles: delta methods fold (1/n)Σ_S as-is, absolute
-            # methods rescale to the cohort mean; non-sampled clients keep
-            # their ENTIRE state tree (gᵢ, momentum, …) bit-frozen
-            msg_mean = part_lib.rescale_message(
-                method, msg_mean, cfg.n, m_cohort)
-            states_new = part_lib.freeze_tree(mask, states_new, states)
-        if want_pods:
-            # the pod tier: per-pod target update + cross hop + server
-            # integration, rng off the round key exactly like ef_round
-            pods_new, g_server_new = hier_lib.round_pods_batched(
-                hops, cfg.schedule, method, msg_mean, pods_st, g_server,
-                r_comp)
-        else:
-            g_server_new = ef_lib.server_step(method, g_server, msg_mean)
-            pods_new = None if hops is None else \
-                hier_lib.trivial_bookkeeping(method, pods_st, msg_mean)
-        pods_tail = (pods_new,) if hops is not None else ()
-
+        grads = jax.vmap(client_grads)(clients, _client_rngs(r_grad, cfg.n))
+        g_server, new_state = dist.uplink_round(
+            efc, grads, ef_state, r_comp, eta=eta_t, step=t)
+        if has_down:
+            # the naive broadcast keeps no memory: its decode rides the 'h'
+            # slot as next round's g_est
+            new_state["h"], _ = dist.downlink_leg(
+                efc, g_server, ef_state["h"], r_comp, memory=cfg.down_memory)
         gn = ef_lib.tree_norm_sq(problem.full_grad(x_next))
         fl = problem.loss(x_next)
-        if has_down:
-            r_down = jax.random.fold_in(r_comp, carrier_lib.DOWNLINK_FOLD)
-            if cfg.schedule is not None:
-                g_est_new, _ = sched_lib.downlink_round_grouped(
-                    cfg.schedule, g_server_new, g_est, r_down,
-                    memory=cfg.down_memory)
-            else:
-                g_est_new, _ = ef_lib.downlink_sync(
-                    down_car, down_comp, g_server_new, g_est, rng=r_down,
-                    memory=cfg.down_memory)
-            return (x_next, states_new, g_server_new, g_est_new,
-                    rng) + pods_tail, (gn, fl)
-        return (x_next, states_new, g_server_new, rng) + pods_tail, (gn, fl)
+        return (x_next, new_state, rng), (gn, fl)
 
-    # h⁰ = g⁰ (downlink_init): the init handshake ships dense state once
-    carry0 = (x0, states, g_server, ef_lib.downlink_init(g_server), rng) \
-        if has_down else (x0, states, g_server, rng)
-    if hops is not None:
-        # per-pod EF memory rides the scan carry (kill-and-resume of the
-        # production runtimes carries the same tree via ef_state['pods'])
-        carry0 = carry0 + (jax.vmap(lambda _: hier_lib.pod_init(x0))(
-            jnp.arange(hops.pods)),)
-    (x_fin, *_), (gns, fls) = jax.lax.scan(
-        step, carry0, jnp.arange(cfg.steps))
+    state0 = dist.init_ef_state(efc, x0, cfg.n, init_grads=g0)
+    (x_fin, _, _), (gns, fls) = jax.lax.scan(
+        step, (x0, state0, rng), jnp.arange(cfg.steps))
     d_total = ef_lib.tree_dim(x0)
     # honest wire accounting follows the plan that actually EXECUTED: when the
     # carrier degrades to the dense plan (unsupported compressor/method,
     # traced ηₜ), what went on the wire was the dense tensor — d words
-    eta_static = None if cfg.time_varying else (
-        cfg.eta if cfg.eta is not None else getattr(method, "eta", 1.0))
+    eta_static = None if cfg.time_varying else eta0
+    part = cfg.participation
+    m_cohort = part.cohort_size(cfg.n) \
+        if part is not None and part.is_sampling else cfg.n
     # Sampled participation: only the m = cohort_size(fraction·n) sampled
     # clients upload, so the honest uplink budget is per-message words × m
     # (DESIGN.md §11). The downlink broadcast still reaches all n links —
@@ -309,13 +172,14 @@ def run(problem, method: ef_lib.Method, cfg: SimConfig, rng: jax.Array) -> Dict:
             "wire_words_down_per_group": tuple(w * cfg.n for w in dn_per),
         }
     else:
-        executed = cfg.carrier \
-            if carrier.plan(method, eta_static) != "dense" else "dense"
+        executed = cfg.carrier if carrier_lib.make(cfg.carrier).plan(
+            method, eta_static) != "dense" else "dense"
         up_words = method.coords_per_message(
             d_total, carrier=executed) * m_cohort
         # downlink: one broadcast message per client link; without a downlink
         # carrier the server ships the dense f32 estimate — d words per client
-        down_each = carrier_lib.downlink_words(down_car, down_comp, d_total) \
+        down_each = carrier_lib.downlink_words(
+            carrier_lib.make(cfg.down_carrier), efc.down_comp(), d_total) \
             if has_down else float(d_total)
         down_words = down_each * cfg.n
         coords = method.coords_per_message(d_total) * m_cohort
@@ -324,6 +188,7 @@ def run(problem, method: ef_lib.Method, cfg: SimConfig, rng: jax.Array) -> Dict:
     # client→server hop IS the cross-pod wire (cross := up, intra := 0);
     # under hops the n client messages ride the fast intra-pod links and the
     # slow cross-pod links carry one compressed innovation per pod
+    hops = efc.effective_hops
     if hops is None:
         intra_words, cross_words = 0.0, up_words
     else:

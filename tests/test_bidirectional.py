@@ -35,6 +35,8 @@ from repro.core import carriers as carrier_lib
 from repro.core import compressors as C
 from repro.core import distributed as D
 from repro.core import ef, problems, simulate
+from repro.core import hierarchy as hier_lib
+from repro.core import participation as part_lib
 
 fuzz = pytest.mark.skipif(
     not HAVE_HYPOTHESIS, reason="hypothesis fuzzing needs hypothesis "
@@ -229,42 +231,96 @@ class _DetQuadratic:
         return 0.5 * jnp.sum(x * x)
 
 
-@pytest.mark.parametrize("up,down,down_comp", [
-    ("dense", "dense", None),
-    ("dense", "quant4", C.BlockTopK(block=2, k_per_block=1)),
-    ("sparse", "sparse", C.BlockTopK(block=2, k_per_block=1)),
-    ("quant8", "quant8", C.Identity()),
-], ids=["no-downlink", "dense-q4", "sparse-sparse", "q8-q8"])
-def test_simulate_matches_ef_round_loop(up, down, down_comp):
-    """core/simulate.py and core/distributed.py must run the SAME round —
-    including the downlink ordering (x steps with h, server integrates the
-    broadcast AFTER the uplink aggregate): the simulator's whole trajectory
-    equals a hand-rolled loop over ``ef_round`` on a deterministic problem."""
-    prob = _DetQuadratic()
-    n, gamma, steps = 3, 1e-2, 12
-    method = ef.EF21SGDM(compressor=C.BlockTopK(block=2, k_per_block=1),
-                         eta=0.2)
+_HALF = part_lib.Participation(mode="sampled", fraction=0.5, seed=3)
+# a cross hop lossy enough that the trajectory leaves the flat one
+_TWO_PODS = hier_lib.Hops(pods=2, cross_carrier="sparse",
+                          cross_compressor=C.TopK(k=1))
+# the fused wire's kernel (interpreted on the CPU) at a whole 128-lane block
+_LANE_BTK = C.BlockTopK(block=128, k_per_block=8)
+
+
+@pytest.mark.parametrize("up,down,down_comp,extra", [
+    ("dense", "dense", None, {}),
+    ("dense", "quant4", C.BlockTopK(block=2, k_per_block=1), {}),
+    ("sparse", "sparse", C.BlockTopK(block=2, k_per_block=1), {}),
+    ("quant8", "quant8", C.Identity(), {}),
+    ("fused_quant8", "dense", None, {"d": 256, "comp": _LANE_BTK}),
+    ("sparse", "quant8", C.Identity(), {"n": 4, "participation": _HALF}),
+    ("quant8", "dense", None, {"n": 4, "hops": _TWO_PODS}),
+], ids=["no-downlink", "dense-q4", "sparse-sparse", "q8-q8", "fused-q8",
+        "sampled-cohort", "two-pods"])
+def test_simulate_matches_ef_round_loop(up, down, down_comp, extra):
+    """core/simulate.py drives core/distributed.py's vmap round — including
+    the downlink ordering (x steps with h, server integrates the broadcast
+    AFTER the uplink aggregate), the sampled cohort (step-indexed) and the
+    pod tier: the simulator's whole trajectory equals a hand-rolled loop
+    over ``ef_round`` on a deterministic problem."""
+    prob = _DetQuadratic(d=extra.get("d", 6))
+    n, gamma, steps = extra.get("n", 3), 1e-2, 12
+    method = ef.EF21SGDM(
+        compressor=extra.get("comp", C.BlockTopK(block=2, k_per_block=1)),
+        eta=0.2)
+    part, hops = extra.get("participation"), extra.get("hops")
     cfg = simulate.SimConfig(n=n, batch_size=1, gamma=gamma, steps=steps,
-                             down_carrier=down, down_compressor=down_comp)
+                             carrier=up, down_carrier=down,
+                             down_compressor=down_comp, participation=part,
+                             hops=hops)
     out = simulate.run_numpy(prob, method, cfg, seed=0)
 
     clients = jnp.arange(n)
     x = prob.init_x()
     g0 = jax.vmap(lambda c: prob.stoch_grad(x, c, None, 1))(clients)
     efc = D.EFConfig(method=method, carrier=up, down_carrier=down,
-                     down_compressor=down_comp)
+                     down_compressor=down_comp, participation=part,
+                     hops=hops)
+    assert efc == cfg.ef_config(method)
     st = D.init_ef_state(efc, x, n, init_grads=g0)
     g_use = st["h"] if efc.has_downlink else st["server"]
     gns = []
-    for _ in range(steps):
+    for t in range(steps):
         x = x - gamma * g_use
         grads = jax.vmap(lambda c: prob.stoch_grad(x, c, None, 1))(clients)
-        g_use, st = D.ef_round(efc, grads, st, None)
+        g_use, st = D.ef_round(efc, grads, st, None, step=jnp.int32(t))
         gns.append(float(jnp.sum(jnp.square(prob.full_grad(x)))))
     np.testing.assert_allclose(out["grad_norm_sq"], np.asarray(gns),
                                rtol=1e-5, atol=1e-7)
     np.testing.assert_allclose(np.asarray(out["x_final"]), np.asarray(x),
                                rtol=1e-5, atol=1e-7)
+
+
+_COMPOSITION_ERRORS = {
+    "sampled-fused-wire": ({"carrier": "fused_quant8",
+                            "participation": _HALF},
+                           "sampled participation cannot run the fused_wire"),
+    "sampled-hops": ({"carrier": "sparse", "participation": _HALF,
+                      "hops": hier_lib.Hops(pods=2)},
+                     "does not compose with hierarchical"),
+    "fused-wire-hops": ({"carrier": "fused_quant8",
+                         "hops": hier_lib.Hops(pods=2)},
+                        "no per-pod message"),
+}
+
+
+@pytest.mark.parametrize("error", sorted(_COMPOSITION_ERRORS))
+@pytest.mark.parametrize("runtime", ["simulate", "ef_round"])
+def test_runtimes_refuse_composition_errors(runtime, error):
+    """Both vmap runtimes refuse the three forbidden compositions with the
+    one check (``distributed.check_composition``) before any round runs."""
+    fields, match = _COMPOSITION_ERRORS[error]
+    prob, n = _DetQuadratic(d=256), 4
+    method = ef.EF21SGDM(compressor=_LANE_BTK, eta=0.2)
+    with pytest.raises(ValueError, match=match):
+        if runtime == "simulate":
+            simulate.run(prob, method,
+                         simulate.SimConfig(n=n, steps=1, **fields),
+                         jax.random.PRNGKey(0))
+        else:
+            efc = D.EFConfig(method=method, **fields)
+            x = prob.init_x()
+            grads = jax.vmap(lambda c: prob.stoch_grad(x, c, None, 1))(
+                jnp.arange(n))
+            st = D.init_ef_state(efc, x, n, init_grads=grads)
+            D.ef_round(efc, grads, st, None, step=jnp.int32(0))
 
 
 # ---------------------------------------------------------------------------

@@ -78,8 +78,9 @@ def test_train_step_g_server_matches_dense(setup, carrier, method_name,
 @pytest.mark.slow
 @pytest.mark.parametrize("carrier", ["sparse", "fused"])
 def test_simulator_matches_dense_on_quadratic(carrier):
-    """All three runtimes share one carrier implementation — the vmap
-    simulator's whole trajectory on a quadratic problem must match dense."""
+    """Both runtimes share one carrier implementation — the simulator's
+    whole trajectory (through the vmap round) on a quadratic problem must
+    match dense."""
     prob = problems.QuadraticT1()
     method = ef.EF21SGDM(compressor=C.BlockTopK(block=2, k_per_block=1),
                          eta=0.2)
